@@ -190,6 +190,24 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+def build_native_runtime() -> str:
+    """Build (or find) pixie_tpu/native's library for THIS machine before
+    JAX is imported: the g++ build is a child process, and no child may
+    start once JAX holds the chip. Loads host_runtime.py on its own (the
+    package __init__ imports JAX); the package's later import finds the
+    .so already built. Returns a one-line status for the log."""
+    import importlib.util
+
+    path = os.path.join(REPO, "pixie_tpu", "native", "host_runtime.py")
+    spec = importlib.util.spec_from_file_location("_native_prebuild", path)
+    mod = importlib.util.module_from_spec(spec)
+    try:
+        spec.loader.exec_module(mod)
+    except Exception as e:  # no toolchain: numpy fallbacks serve
+        return f"not built ({type(e).__name__}: {e})"
+    return f"built for this machine: {os.path.basename(mod.SO_PATH)}"
+
+
 GATE_TOLERANCE = 0.10  # >10% below best-ever trips the gate
 _SCHEMA_V = "v1"  # bump to invalidate cached datasets
 
@@ -306,6 +324,277 @@ def _pick(rng, options: np.ndarray, p: list[float], m: int) -> np.ndarray:
     return options[np.searchsorted(cum, rng.random(m), side="right")]
 
 
+# ---- shared datasets: schemas, generators, loaders, queries ----------------
+# Module level so chip_smoke.py drives the device path with exactly the
+# bench's tables and PxL. ``create_table(name, relation, **kw)`` is the
+# caller's table factory (the bench's keeps HBM rings off).
+
+_WRITE_CHUNK = 16_000_000
+# Staged device block rows (BENCH_BLOCK_ROWS default): a block clears
+# the sort-compact lanes' row floor (ops/segment.SORTED_MIN_ROWS).
+BLOCK_ROWS = 1 << 21
+
+
+def service_names(n_services: int) -> np.ndarray:
+    return np.array([f"ns/svc-{i}" for i in range(n_services)], dtype=object)
+
+
+def _write_chunked(table, n: int, columns) -> None:
+    """Append rows [0, n) in chunks; ``columns(off, m)`` -> pydict."""
+    for off in range(0, n, _WRITE_CHUNK):
+        m = min(_WRITE_CHUNK, n - off)
+        cols = columns(off, m)
+        cols["time_"] = np.arange(off, off + m, dtype=np.int64) * 1000
+        table.write_pydict(cols)
+    table.compact()
+    table.stop()
+    assert table.min_row_id() == 0 and table.end_row_id() == n, (
+        "table expired rows; the metric would be inflated"
+    )
+
+
+def _identity_codes(dictionary, names) -> None:
+    # Identity codes 0..n-1 (encode() would assign codes in SORTED order).
+    for name in names:
+        dictionary.get_code(name)
+
+
+def gen_http_events(n_rows: int, n_services: int, seed: int = 42) -> dict:
+    """http_events columns plus host truth accumulated while generating."""
+    rng = np.random.default_rng(seed)
+    svc_idx = np.empty(n_rows, np.uint8)
+    status = np.empty(n_rows, np.uint16)
+    latency = np.empty(n_rows, np.float64)
+    tc = np.zeros(n_services, np.int64)
+    te = np.zeros(n_services, np.int64)
+    th = np.zeros((n_services, TRUTH_BINS), np.int64)
+    opts = np.array([200, 301, 404, 500], np.uint16)
+    for off in range(0, n_rows, _WRITE_CHUNK):
+        m = min(_WRITE_CHUNK, n_rows - off)
+        si = rng.integers(0, n_services, m, dtype=np.uint8)
+        st = _pick(rng, opts, [0.85, 0.05, 0.05, 0.05], m)
+        la = rng.exponential(3e7, m)
+        svc_idx[off : off + m] = si
+        status[off : off + m] = st
+        latency[off : off + m] = la
+        tc += np.bincount(si, minlength=n_services)
+        te += np.bincount(
+            si, weights=(st >= 400), minlength=n_services
+        ).astype(np.int64)
+        bins = np.digitize(la, TRUTH_EDGES)
+        th += np.bincount(
+            si.astype(np.int64) * TRUTH_BINS + bins,
+            minlength=n_services * TRUTH_BINS,
+        ).reshape(n_services, TRUTH_BINS)
+        log(f"http_events: generated {off + m}/{n_rows} rows")
+    return {
+        "svc_idx": svc_idx,
+        "status": status,
+        "latency": latency,
+        "true_count": tc,
+        "true_errors": te,
+        "true_hist": th,
+    }
+
+
+def load_http_events(create_table, d: dict, services, name="http_events"):
+    from pixie_tpu.table.column import DictColumn
+    from pixie_tpu.types import DataType, Relation, SemanticType
+
+    rel = Relation.of(
+        ("time_", DataType.TIME64NS, SemanticType.ST_TIME_NS),
+        ("service", DataType.STRING, SemanticType.ST_SERVICE_NAME),
+        ("resp_status", DataType.INT64),
+        ("latency", DataType.FLOAT64, SemanticType.ST_DURATION_NS),
+    )
+    table = create_table(name, rel, size_limit=1 << 42)
+    svc_dict = table.dictionaries["service"]
+    _identity_codes(svc_dict, services)
+    _write_chunked(
+        table,
+        len(d["svc_idx"]),
+        lambda off, m: {
+            "service": DictColumn(
+                d["svc_idx"][off : off + m].astype(np.int32), svc_dict
+            ),
+            "resp_status": d["status"][off : off + m],
+            "latency": d["latency"][off : off + m],
+        },
+    )
+    return table
+
+
+QUERY_SERVICE_STATS = (  # config 2
+    "df = px.DataFrame(table='http_events')\n"
+    "df.failure = df.resp_status >= 400\n"
+    "stats = df.groupby(['service']).agg(\n"
+    "    throughput=('time_', px.count),\n"
+    "    error_rate=('failure', px.mean),\n"
+    "    latency=('latency', px.quantiles),\n"
+    ")\n"
+    "px.display(stats, 'service_stats')\n"
+)
+
+QUERY_SKETCHES = (  # config 5
+    "df = px.DataFrame(table='http_events')\n"
+    "s = df.groupby(['service']).agg(\n"
+    "    lat=('latency', px.quantiles_tdigest),\n"
+    "    freq=('resp_status', px.count_min),\n"
+    ")\n"
+    "px.display(s, 'sketches')\n"
+)
+
+
+def verify_service_stats(rows: dict, d: dict, services) -> None:
+    """Config-2 truth check: exact counts and error rates; p50/p99 of the
+    sketch within 4% of the independent numpy histogram."""
+    by_svc = {s: i for i, s in enumerate(rows["service"])}
+    assert len(by_svc) == len(services), f"got {len(by_svc)} groups"
+    assert sum(rows["throughput"]) == len(d["svc_idx"]), "row count mismatch"
+    for j, name in enumerate(services):
+        i = by_svc[name]
+        assert rows["throughput"][i] == d["true_count"][j]
+        want_er = d["true_errors"][j] / d["true_count"][j]
+        assert abs(rows["error_rate"][i] - want_er) < 1e-9
+        q = json.loads(rows["latency"][i])
+        for key, qq in (("p50", 0.50), ("p99", 0.99)):
+            want = truth_quantile(d["true_hist"][j], qq)
+            # sketch ~1.4% rel err + truth-bin ~0.7% -> 4% is
+            # decisive: a wrong kernel is off by far more.
+            assert abs(q[key] - want) <= 0.04 * want, (name, key)
+
+
+N_HOSTS = 64  # conn_flows pods per side
+
+
+def gen_conn_flows(n_rows: int, seed: int = 45) -> dict:
+    rng = np.random.default_rng(seed)
+    return {
+        "src": rng.integers(0, N_HOSTS, n_rows, dtype=np.uint8),
+        "dst": rng.integers(0, N_HOSTS, n_rows, dtype=np.uint8),
+        "port": rng.integers(1024, 65535, n_rows, dtype=np.uint16),
+        "bs": rng.integers(0, 1 << 20, n_rows, dtype=np.uint32),
+        "br": rng.integers(0, 1 << 20, n_rows, dtype=np.uint32),
+    }
+
+
+def load_conn_flows(create_table, d: dict):
+    from pixie_tpu.table.column import DictColumn
+    from pixie_tpu.types import DataType, Relation, SemanticType
+
+    S, I = DataType.STRING, DataType.INT64
+    rel = Relation.of(
+        ("time_", DataType.TIME64NS, SemanticType.ST_TIME_NS),
+        ("src", S),
+        ("dst", S),
+        ("remote_port", I),
+        ("bytes_sent", I),
+        ("bytes_recv", I),
+    )
+    table = create_table("conn_flows", rel, size_limit=1 << 42)
+    hosts = [f"default/pod-{i}" for i in range(N_HOSTS)]
+    for col in ("src", "dst"):
+        _identity_codes(table.dictionaries[col], hosts)
+    _write_chunked(
+        table,
+        len(d["src"]),
+        lambda off, m: {
+            "src": DictColumn(
+                d["src"][off : off + m].astype(np.int32),
+                table.dictionaries["src"],
+            ),
+            "dst": DictColumn(
+                d["dst"][off : off + m].astype(np.int32),
+                table.dictionaries["dst"],
+            ),
+            "remote_port": d["port"][off : off + m],
+            "bytes_sent": d["bs"][off : off + m],
+            "bytes_recv": d["br"][off : off + m],
+        },
+    )
+    return table
+
+
+QUERY_NET_FLOW = (  # config 3
+    "df = px.DataFrame(table='conn_flows')\n"
+    "s = df.groupby(['src', 'dst']).agg(\n"
+    "    bytes_sent=('bytes_sent', px.sum),\n"
+    "    bytes_recv=('bytes_recv', px.sum),\n"
+    "    ports=('remote_port', px.approx_count_distinct),\n"
+    ")\n"
+    "px.display(s, 'flows')\n"
+)
+
+
+def gen_join_fact(n_join: int, n_services: int, seed: int = 46) -> dict:
+    rng = np.random.default_rng(seed)
+    return {
+        "svc_idx": rng.integers(0, n_services, n_join, dtype=np.uint8),
+        "latency": rng.exponential(3e7, n_join),
+    }
+
+
+def load_join_tables(create_table, d: dict, services) -> None:
+    """svc_owners (dim, one row per service) and join_fact (fact)."""
+    from pixie_tpu.table.column import DictColumn
+    from pixie_tpu.types import DataType, Relation, SemanticType
+
+    S = DataType.STRING
+    dim_rel = Relation.of(
+        ("svc", S, SemanticType.ST_SERVICE_NAME),
+        ("owner", S),
+    )
+    td = create_table("svc_owners", dim_rel)
+    td.write_pydict(
+        {
+            "svc": services,
+            "owner": np.array(
+                [f"team-{i % 4}" for i in range(len(services))],
+                dtype=object,
+            ),
+        }
+    )
+    td.compact()
+    td.stop()
+    fact_rel = Relation.of(
+        ("time_", DataType.TIME64NS, SemanticType.ST_TIME_NS),
+        ("service", S, SemanticType.ST_SERVICE_NAME),
+        ("latency", DataType.FLOAT64, SemanticType.ST_DURATION_NS),
+    )
+    tf = create_table("join_fact", fact_rel, size_limit=1 << 42)
+    fd = tf.dictionaries["service"]
+    _identity_codes(fd, services)
+    _write_chunked(
+        tf,
+        len(d["svc_idx"]),
+        lambda off, m: {
+            "service": DictColumn(
+                d["svc_idx"][off : off + m].astype(np.int32), fd
+            ),
+            "latency": d["latency"][off : off + m],
+        },
+    )
+
+
+QUERY_JOIN = (  # config 8
+    "l = px.DataFrame(table='svc_owners')\n"
+    "r = px.DataFrame(table='join_fact')\n"
+    "j = l.merge(r, how='inner', left_on=['svc'],"
+    " right_on=['service'], suffixes=['', '_r'])\n"
+    "px.display(j, 'joined')\n"
+)
+
+
+def verify_join(rows: dict, n_join: int) -> None:
+    assert len(rows["time_"]) == n_join, len(rows["time_"])
+    # Every emitted pair carries equal key columns from both sides — a
+    # wrong gather/merge shows up here immediately.
+    assert np.array_equal(
+        np.asarray(rows["svc"], dtype=object),
+        np.asarray(rows["service"], dtype=object),
+    ), "join key mismatch between sides"
+
+
 class Ledger:
     """Incremental BENCH_DETAIL.json writer: every finished config is
     persisted immediately so a driver timeout later cannot lose it."""
@@ -357,7 +646,7 @@ def main() -> None:
     n_host = int(os.environ.get("BENCH_HOST_ROWS", 8_000_000))
     n_services = int(os.environ.get("BENCH_SERVICES", 16))
     runs = int(os.environ.get("BENCH_RUNS", 5))
-    block_rows = int(os.environ.get("BENCH_BLOCK_ROWS", 1 << 21))
+    block_rows = int(os.environ.get("BENCH_BLOCK_ROWS", BLOCK_ROWS))
     order = [
         c.strip()
         for c in os.environ.get("BENCH_CONFIGS", "2,5,4,1,0,3").split(",")
@@ -371,6 +660,7 @@ def main() -> None:
         raise SystemExit(f"BENCH_CONFIGS has unknown entries: {unknown}")
     configs = set(order)
 
+    log(f"native host runtime: {build_native_runtime()}")
     import jax
 
     # Persistent XLA compilation cache: repeat cold queries (including the
@@ -378,16 +668,15 @@ def main() -> None:
     # BENCH_CLEAR_JAX_CACHE=1 wipes it first so cold-compile numbers are
     # honest (stage_compile measures a REAL compile, not a deserialize)
     # and compile regressions gate instead of hiding behind a warm cache.
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR", os.path.join(REPO, ".jax_cache")
-    )
+    from pixie_tpu.utils import compile_cache
+
+    cache_dir = compile_cache.cache_dir()
     if os.environ.get("BENCH_CLEAR_JAX_CACHE"):
         import shutil
 
         shutil.rmtree(cache_dir, ignore_errors=True)
         log(f"cleared persistent compilation cache {cache_dir}")
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    compile_cache.enable()
 
     from jax.sharding import Mesh
 
@@ -470,9 +759,7 @@ def main() -> None:
     )
     cache = DatasetCache()
     ledger = Ledger()
-    services = np.array(
-        [f"ns/svc-{i}" for i in range(n_services)], dtype=object
-    )
+    services = service_names(n_services)
     headline_printed = False
 
     def breakdown() -> dict:
@@ -496,7 +783,7 @@ def main() -> None:
         snap.setdefault("warm_compile", 0.0)
         snap.setdefault("prewarm_hit", 0.0)
         # r13 keys: the staging codec + resident-ingest breakdown.
-        # wire_bytes is what the host→HBM tunnel actually carried;
+        # wire_bytes is what the host→HBM transfer actually carried;
         # stage_bytes is what landed (decoded blocks); codec_ratio is
         # their quotient — the 'kill the transfer floor' headline.
         # stage_encode/stage_decode are the host encode and device
@@ -556,115 +843,33 @@ def main() -> None:
         ("resp_status", I),
         ("latency", F, SemanticType.ST_DURATION_NS),
     )
-    true_count = true_errors = true_hist = None
+    http_data: dict = {}
     _built = set()
 
     def ensure_http_table():
-        nonlocal true_count, true_errors, true_hist
         if "http" in _built:
             return
         _built.add("http")
-
-        def build_http():
-            rng = np.random.default_rng(42)
-            svc_idx = np.empty(n_rows, np.uint8)
-            status = np.empty(n_rows, np.uint16)
-            latency = np.empty(n_rows, np.float64)
-            tc = np.zeros(n_services, np.int64)
-            te = np.zeros(n_services, np.int64)
-            th = np.zeros((n_services, TRUTH_BINS), np.int64)
-            chunk = 16_000_000
-            opts = np.array([200, 301, 404, 500], np.uint16)
-            for off in range(0, n_rows, chunk):
-                m = min(chunk, n_rows - off)
-                si = rng.integers(0, n_services, m, dtype=np.uint8)
-                st = _pick(rng, opts, [0.85, 0.05, 0.05, 0.05], m)
-                la = rng.exponential(3e7, m)
-                svc_idx[off : off + m] = si
-                status[off : off + m] = st
-                latency[off : off + m] = la
-                tc += np.bincount(si, minlength=n_services)
-                te += np.bincount(
-                    si, weights=(st >= 400), minlength=n_services
-                ).astype(np.int64)
-                bins = np.digitize(la, TRUTH_EDGES)
-                th += np.bincount(
-                    si.astype(np.int64) * TRUTH_BINS + bins,
-                    minlength=n_services * TRUTH_BINS,
-                ).reshape(n_services, TRUTH_BINS)
-                log(f"http_events: generated {off + m}/{n_rows} rows")
-            return {
-                "svc_idx": svc_idx,
-                "status": status,
-                "latency": latency,
-                "true_count": tc,
-                "true_errors": te,
-                "true_hist": th,
-            }
-
-        d = cache.get_or_build(f"http_{n_rows}_{n_services}_s42", build_http)
-        true_count = d["true_count"]
-        true_errors = d["true_errors"]
-        true_hist = d["true_hist"]
-        t_gen = time.perf_counter()
-        table = create_table_no_ring(
-            "http_events", rel, size_limit=1 << 42
-        )
-        svc_dict = table.dictionaries["service"]
-        for name in services:  # identity codes 0..n-1 (encode() would
-            svc_dict.get_code(name)  # assign codes in SORTED order)
-        chunk = 16_000_000
-        for off in range(0, n_rows, chunk):
-            m = min(chunk, n_rows - off)
-            table.write_pydict(
-                {
-                    "time_": np.arange(off, off + m, dtype=np.int64) * 1000,
-                    "service": DictColumn(
-                        d["svc_idx"][off : off + m].astype(np.int32),
-                        svc_dict,
-                    ),
-                    "resp_status": d["status"][off : off + m],
-                    "latency": d["latency"][off : off + m],
-                }
+        http_data.update(
+            cache.get_or_build(
+                f"http_{n_rows}_{n_services}_s42",
+                lambda: gen_http_events(n_rows, n_services),
             )
-        table.compact()
-        table.stop()
-        assert table.min_row_id() == 0 and table.end_row_id() == n_rows, (
-            "table expired rows; the metric would be inflated"
         )
+        t_gen = time.perf_counter()
+        load_http_events(create_table_no_ring, http_data, services)
         log(f"http_events table built in {time.perf_counter() - t_gen:.1f}s")
 
     # ---- config 2: service_stats (headline) -------------------------------
     def run_config_2():
         nonlocal headline_printed
         ensure_http_table()
-        query = (
-            "df = px.DataFrame(table='http_events')\n"
-            "df.failure = df.resp_status >= 400\n"
-            "stats = df.groupby(['service']).agg(\n"
-            "    throughput=('time_', px.count),\n"
-            "    error_rate=('failure', px.mean),\n"
-            "    latency=('latency', px.quantiles),\n"
-            ")\n"
-            "px.display(stats, 'service_stats')\n"
-        )
+        query = QUERY_SERVICE_STATS
 
         def verify(result) -> None:
-            rows = result.table("service_stats")
-            by_svc = {s: i for i, s in enumerate(rows["service"])}
-            assert len(by_svc) == n_services, f"got {len(by_svc)} groups"
-            assert sum(rows["throughput"]) == n_rows, "row count mismatch"
-            for j, name in enumerate(services):
-                i = by_svc[name]
-                assert rows["throughput"][i] == true_count[j]
-                want_er = true_errors[j] / true_count[j]
-                assert abs(rows["error_rate"][i] - want_er) < 1e-9
-                q = json.loads(rows["latency"][i])
-                for key, qq in (("p50", 0.50), ("p99", 0.99)):
-                    want = truth_quantile(true_hist[j], qq)
-                    # sketch ~1.4% rel err + truth-bin ~0.7% -> 4% is
-                    # decisive: a wrong kernel is off by far more.
-                    assert abs(q[key] - want) <= 0.04 * want, (name, key)
+            verify_service_stats(
+                result.table("service_stats"), http_data, services
+            )
 
         result, cold2, bd = cold_run(query)
         log(f"config2 cold (compile+stage+run) {cold2:.1f}s {bd}")
@@ -698,14 +903,7 @@ def main() -> None:
     # ---- config 5: streaming sketches (t-digest + count-min) --------------
     def run_config_5():
         ensure_http_table()
-        q5 = (
-            "df = px.DataFrame(table='http_events')\n"
-            "s = df.groupby(['service']).agg(\n"
-            "    lat=('latency', px.quantiles_tdigest),\n"
-            "    freq=('resp_status', px.count_min),\n"
-            ")\n"
-            "px.display(s, 'sketches')\n"
-        )
+        q5 = QUERY_SKETCHES
         r5, cold5, bd = cold_run(q5)
         best, last = best_of(lambda: carnot.execute_query(q5), runs)
         assert len(last.table("sketches")["service"]) == n_services
@@ -910,68 +1108,11 @@ def main() -> None:
 
     # ---- config 3: net_flow groupby(src,dst) sum + HLL distinct -----------
     def run_config_3():
-        conn_rel = Relation.of(
-            ("time_", T, SemanticType.ST_TIME_NS),
-            ("src", S),
-            ("dst", S),
-            ("remote_port", I),
-            ("bytes_sent", I),
-            ("bytes_recv", I),
+        d3 = cache.get_or_build(
+            f"flows_{n_small}_s45", lambda: gen_conn_flows(n_small)
         )
-        t3 = create_table_no_ring(
-            "conn_flows", conn_rel, size_limit=1 << 42
-        )
-        hosts = np.array(
-            [f"default/pod-{i}" for i in range(64)], dtype=object
-        )
-        for col in ("src", "dst"):
-            for h in hosts:
-                t3.dictionaries[col].get_code(h)
-
-        def build_flows():
-            rng = np.random.default_rng(45)
-            return {
-                "src": rng.integers(0, 64, n_small, dtype=np.uint8),
-                "dst": rng.integers(0, 64, n_small, dtype=np.uint8),
-                "port": rng.integers(1024, 65535, n_small, dtype=np.uint16),
-                "bs": rng.integers(0, 1 << 20, n_small, dtype=np.uint32),
-                "br": rng.integers(0, 1 << 20, n_small, dtype=np.uint32),
-            }
-
-        d3 = cache.get_or_build(f"flows_{n_small}_s45", build_flows)
-        chunk = 16_000_000
-        for off in range(0, n_small, chunk):
-            m = min(chunk, n_small - off)
-            t3.write_pydict(
-                {
-                    "time_": np.arange(off, off + m, dtype=np.int64) * 1000,
-                    "src": DictColumn(
-                        d3["src"][off : off + m].astype(np.int32),
-                        t3.dictionaries["src"],
-                    ),
-                    "dst": DictColumn(
-                        d3["dst"][off : off + m].astype(np.int32),
-                        t3.dictionaries["dst"],
-                    ),
-                    "remote_port": d3["port"][off : off + m],
-                    "bytes_sent": d3["bs"][off : off + m],
-                    "bytes_recv": d3["br"][off : off + m],
-                }
-            )
-        t3.compact()
-        t3.stop()
-        assert t3.min_row_id() == 0 and t3.end_row_id() == n_small, (
-            "table expired rows; the metric would be inflated"
-        )
-        q3 = (
-            "df = px.DataFrame(table='conn_flows')\n"
-            "s = df.groupby(['src', 'dst']).agg(\n"
-            "    bytes_sent=('bytes_sent', px.sum),\n"
-            "    bytes_recv=('bytes_recv', px.sum),\n"
-            "    ports=('remote_port', px.approx_count_distinct),\n"
-            ")\n"
-            "px.display(s, 'flows')\n"
-        )
+        load_conn_flows(create_table_no_ring, d3)
+        q3 = QUERY_NET_FLOW
         _, cold3, bd = cold_run(q3)
         best, last = best_of(lambda: carnot.execute_query(q3), runs)
         assert sum(last.table("flows")["bytes_sent"]) > 0
@@ -1092,73 +1233,15 @@ def main() -> None:
         # engages (4M rows ≥ device_join_min_rows, output ≤
         # device_join_max_out); join_lane records what actually ran.
         n_join = int(os.environ.get("BENCH_JOIN_ROWS", 4_000_000))
-        dim_rel = Relation.of(
-            ("svc", S, SemanticType.ST_SERVICE_NAME),
-            ("owner", S),
+        d8 = cache.get_or_build(
+            f"joinfact_{n_join}_s46",
+            lambda: gen_join_fact(n_join, n_services),
         )
-        td = create_table_no_ring("svc_owners", dim_rel)
-        td.write_pydict(
-            {
-                "svc": services,
-                "owner": np.array(
-                    [f"team-{i % 4}" for i in range(n_services)],
-                    dtype=object,
-                ),
-            }
-        )
-        td.compact()
-        td.stop()
-        fact_rel = Relation.of(
-            ("time_", T, SemanticType.ST_TIME_NS),
-            ("service", S, SemanticType.ST_SERVICE_NAME),
-            ("latency", F, SemanticType.ST_DURATION_NS),
-        )
-        tf = create_table_no_ring("join_fact", fact_rel, size_limit=1 << 42)
-        fd = tf.dictionaries["service"]
-        for name in services:
-            fd.get_code(name)
-
-        def build_join_fact():
-            rng = np.random.default_rng(46)
-            return {
-                "svc_idx": rng.integers(
-                    0, n_services, n_join, dtype=np.uint8
-                ),
-                "latency": rng.exponential(3e7, n_join),
-            }
-
-        d8 = cache.get_or_build(f"joinfact_{n_join}_s46", build_join_fact)
-        chunk = 16_000_000
-        for off in range(0, n_join, chunk):
-            m = min(chunk, n_join - off)
-            tf.write_pydict(
-                {
-                    "time_": np.arange(off, off + m, dtype=np.int64) * 1000,
-                    "service": DictColumn(
-                        d8["svc_idx"][off : off + m].astype(np.int32), fd
-                    ),
-                    "latency": d8["latency"][off : off + m],
-                }
-            )
-        tf.compact()
-        tf.stop()
-        q8 = (
-            "l = px.DataFrame(table='svc_owners')\n"
-            "r = px.DataFrame(table='join_fact')\n"
-            "j = l.merge(r, how='inner', left_on=['svc'],"
-            " right_on=['service'], suffixes=['', '_r'])\n"
-            "px.display(j, 'joined')\n"
-        )
+        load_join_tables(create_table_no_ring, d8, services)
+        q8 = QUERY_JOIN
 
         def verify(result) -> None:
-            rows = result.table("joined")
-            assert len(rows["time_"]) == n_join, len(rows["time_"])
-            # Every emitted pair carries equal key columns from both
-            # sides — a wrong gather/merge shows up here immediately.
-            assert np.array_equal(
-                np.asarray(rows["svc"], dtype=object),
-                np.asarray(rows["service"], dtype=object),
-            ), "join key mismatch between sides"
+            verify_join(result.table("joined"), n_join)
 
         result, cold8, bd = cold_run(q8)
         verify(result)

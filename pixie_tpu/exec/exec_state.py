@@ -99,14 +99,14 @@ class ExecState:
         self.bridge_token = bridge_token
 
     def compute_device(self):
+        """The host engine's device. The CPU backend makes the host engine
+        the CPU reference; a missing backend raises instead of running it
+        on the default device (the chip) in silence."""
         if self.compute_backend is None:
             return None
-        try:
-            import jax
+        import jax
 
-            return jax.local_devices(backend=self.compute_backend)[0]
-        except Exception:
-            return None
+        return jax.local_devices(backend=self.compute_backend)[0]
 
     # -- limit/source abort (ref: exec_state keep-running + limit signal) ---
     def stop_sources(self) -> None:

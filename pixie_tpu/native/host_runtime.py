@@ -1,12 +1,14 @@
-"""ctypes bindings for host_runtime.cc (built lazily, cached by source
-hash). Raises at import when no toolchain is available — callers catch
-and fall back to numpy."""
+"""ctypes bindings for host_runtime.cc (built lazily on the machine that
+loads it). Raises at import when no toolchain is available — callers
+catch and fall back to numpy."""
 
 from __future__ import annotations
 
 import ctypes
+import glob
 import hashlib
 import os
+import platform
 import subprocess
 import tempfile
 
@@ -14,23 +16,39 @@ import numpy as np
 
 _DIR = os.path.dirname(__file__)
 _SRC = os.path.join(_DIR, "host_runtime.cc")
+_CXXFLAGS = ("-O3", "-march=native", "-std=c++17", "-shared", "-fPIC")
+
+
+def _machine_id() -> bytes:
+    """What a ``-march=native`` build is valid for: this boot of this
+    machine (a checkout copied to another host — the chip machine —
+    never loads a binary built for a different CPU)."""
+    parts = [platform.machine()]
+    for path in ("/proc/sys/kernel/random/boot_id", "/proc/cpuinfo"):
+        try:
+            with open(path) as f:
+                text = f.read()
+        except OSError:
+            continue
+        # cpuinfo: the first processor's block (model + feature flags).
+        parts.append(text.split("\n\n", 1)[0])
+    return "\0".join(parts).encode()
 
 
 def _build() -> str:
+    h = hashlib.sha256()
     with open(_SRC, "rb") as f:
-        digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    so_path = os.path.join(_DIR, f"_host_runtime_{digest}.so")
+        h.update(f.read())
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(_machine_id())
+    so_path = os.path.join(_DIR, f"_host_runtime_{h.hexdigest()[:16]}.so")
     if os.path.exists(so_path):
         return so_path
-    # Stale builds from older sources are superseded, not reused.
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_DIR)
     os.close(fd)
     try:
         subprocess.run(
-            [
-                "g++", "-O3", "-march=native", "-std=c++17", "-shared",
-                "-fPIC", _SRC, "-o", tmp,
-            ],
+            ["g++", *_CXXFLAGS, _SRC, "-o", tmp],
             check=True,
             capture_output=True,
             timeout=120,
@@ -40,10 +58,18 @@ def _build() -> str:
         if os.path.exists(tmp):
             os.unlink(tmp)
         raise
+    # Builds for other sources or machines are superseded, not reused.
+    for stale in glob.glob(os.path.join(_DIR, "_host_runtime_*.so")):
+        if stale != so_path:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
     return so_path
 
 
-_lib = ctypes.CDLL(_build())
+SO_PATH = _build()
+_lib = ctypes.CDLL(SO_PATH)
 
 _lib.fnv1a64_batch.argtypes = [
     ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,

@@ -22,8 +22,6 @@ lane of record; small-domain columns keep the r7 MXU cell lane
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -115,21 +113,48 @@ def _alpha(m: int) -> float:
     return 0.7213 / (1 + 1.079 / m)
 
 
-def estimate(state):
-    """Per-group cardinality estimates [num_groups] float64 with the
-    standard small-range (linear counting) and 32-bit large-range
+def estimate_terms(state):
+    """The estimate's integer inputs per group, [G, 3] int64: the sum of
+    2^(32 - rho), the zero-register count, and m. Registers hold
+    rho <= 33 - precision, so every term is an integer and the sum
+    (<= m * 2^32) fits int64: exact on every backend, in any order. This
+    is the device half of finalize; the host forms the f64 estimate
+    (estimate_from_terms), since the TPU emulates f64 and its estimates
+    rounded differently from the host engine's (PR 21's chip smoke)."""
+    g, m = state.shape
+    terms = jnp.left_shift(jnp.int64(1), 32 - state.astype(jnp.int64))
+    return jnp.stack(
+        [
+            terms.sum(axis=1),
+            jnp.sum(state == 0, axis=1, dtype=jnp.int64),
+            jnp.full((g,), m, jnp.int64),
+        ],
+        axis=1,
+    )
+
+
+def estimate_from_terms(terms) -> np.ndarray:
+    """Per-group cardinality estimates (int64, rounded) on the host, with
+    the standard small-range (linear counting) and 32-bit large-range
     corrections. The large-range term compensates hash collisions as raw
     estimates approach the 2^32 hash space (registers derive from 32-bit
     hashes since r4; without it, estimates undercount past ~2^32/30)."""
-    g, m = state.shape
-    regs = state.astype(jnp.float64)
-    raw = _alpha(m) * m * m / jnp.sum(jnp.power(2.0, -regs), axis=1)
-    zeros = jnp.sum(state == 0, axis=1).astype(jnp.float64)
-    linear = m * jnp.log(jnp.maximum(m / jnp.maximum(zeros, 1e-9), 1.0))
-    use_linear = (raw <= 2.5 * m) & (zeros > 0)
+    terms = np.asarray(terms, np.int64)
+    if terms.shape[0] == 0:
+        return np.zeros(0, np.int64)
+    m = int(terms[0, 2])
     two32 = float(1 << 32)
-    large = -two32 * jnp.log(
-        jnp.maximum(1.0 - jnp.minimum(raw, two32 * 0.9999) / two32, 1e-12)
+    raw = _alpha(m) * m * m * two32 / terms[:, 0].astype(np.float64)
+    zeros = terms[:, 1].astype(np.float64)
+    linear = m * np.log(np.maximum(m / np.maximum(zeros, 1e-9), 1.0))
+    use_linear = (raw <= 2.5 * m) & (zeros > 0)
+    large = -two32 * np.log(
+        np.maximum(1.0 - np.minimum(raw, two32 * 0.9999) / two32, 1e-12)
     )
-    corrected = jnp.where(raw > two32 / 30.0, large, raw)
-    return jnp.where(use_linear, linear, corrected)
+    corrected = np.where(raw > two32 / 30.0, large, raw)
+    return np.round(np.where(use_linear, linear, corrected)).astype(np.int64)
+
+
+def estimate(state) -> np.ndarray:
+    """Per-group cardinality estimates [num_groups] (int64, host)."""
+    return estimate_from_terms(estimate_terms(state))

@@ -34,23 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax>=0.4.35 exposes shard_map at top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# Replication checking kwarg was renamed check_rep -> check_vma across jax
-# versions; probe the actual signature once.
-import inspect as _inspect
-
-_SM_PARAMS = _inspect.signature(shard_map).parameters
-if "check_vma" in _SM_PARAMS:
-    _SM_CHECK_KW = {"check_vma": False}
-elif "check_rep" in _SM_PARAMS:  # pragma: no cover - older jax
-    _SM_CHECK_KW = {"check_rep": False}
-else:  # pragma: no cover
-    _SM_CHECK_KW = {}
-
 from pixie_tpu.compiler.analyzer import substitute
 from pixie_tpu.exec.expression_evaluator import ExpressionEvaluator
 from pixie_tpu.exec.group_encoder import GroupEncoder
@@ -179,6 +162,26 @@ from pixie_tpu.parallel.staging import (  # noqa: E402
     reset_cold_profile,
     timed as _timed,
 )
+
+
+def _f64_as_bits(cols: dict) -> dict:
+    """Float64 columns as their int64 bit patterns, for columns the device
+    only moves (join payload). The TPU emulates f64 with f32 pairs, so an
+    f64 value gathered there came back with its low mantissa bits changed
+    (PR 21's chip smoke); int64 bits pass through exactly, and the host
+    views them back (_f64_from_bits)."""
+    return {
+        c: a.view(np.int64)
+        if isinstance(a, np.ndarray) and a.dtype == np.float64
+        else a
+        for c, a in cols.items()
+    }
+
+
+def _f64_from_bits(a: np.ndarray, dt) -> np.ndarray:
+    if dt == DataType.FLOAT64:
+        return a.astype(np.int64).view(np.float64)
+    return a.astype(host_dtype(dt))
 
 
 @dataclasses.dataclass
@@ -2546,12 +2549,12 @@ class MeshExecutor:
             in_specs = tuple([P(axis)] * n_sharded + [P()] * n_repl)
             n_out = 1 + len(stat_kinds)
             program = jax.jit(
-                shard_map(
+                jax.shard_map(
                     shard_fn,
                     mesh=self.mesh,
                     in_specs=in_specs,
                     out_specs=tuple([P()] * n_out),
-                    **_SM_CHECK_KW,
+                    check_vma=False,
                 )
             )
             self._program_cache[sig] = (program, len(aux_order), None)
@@ -2779,12 +2782,12 @@ class MeshExecutor:
             )
             in_specs = tuple([P(axis)] * n_sharded + [P()] * n_repl)
             program = jax.jit(
-                shard_map(
+                jax.shard_map(
                     shard_fn,
                     mesh=self.mesh,
                     in_specs=in_specs,
                     out_specs=P(),
-                    **_SM_CHECK_KW,
+                    check_vma=False,
                 )
             )
             self._program_cache[sig] = (program, len(aux_order), None)
@@ -3135,6 +3138,7 @@ class MeshExecutor:
             ck_l, lt, m.left_source_op, cols_l,
             _KeyPlan(host_gids=kl.astype(np.int32), num_groups=K),
             row_sel=left_sel,
+            f64_bits=True,
         )
         if staged_l is None or staged_l.num_rows != nl:
             return None
@@ -3142,6 +3146,7 @@ class MeshExecutor:
             ck_r, rt, m.right_source_op, cols_r,
             _KeyPlan(host_gids=kr.astype(np.int32), num_groups=K),
             row_sel=right_sel,
+            f64_bits=True,
         )
         if staged_r is None or staged_r.num_rows != nr:
             return None
@@ -3219,7 +3224,6 @@ class MeshExecutor:
         a multiset-identical one otherwise; join row order is not a
         contract, preserves_time_order=False)."""
         from pixie_tpu.ops import segment as _segment
-        from pixie_tpu.types.dtypes import host_dtype
 
         l_names = sorted(staged_l.blocks)
         r_names = sorted(staged_r.blocks)
@@ -3349,12 +3353,12 @@ class MeshExecutor:
             n_sharded = len(l_names) + 2 + len(r_names) + 2
             n_repl = 1 + (1 if l_narrow else 0) + (1 if r_narrow else 0)
             program = jax.jit(
-                shard_map(
+                jax.shard_map(
                     shard_fn,
                     mesh=self.mesh,
                     in_specs=tuple([P(axis)] * n_sharded + [P()] * n_repl),
                     out_specs=tuple([P()] * len(out_plan)),
-                    **_SM_CHECK_KW,
+                    check_vma=False,
                 )
             )
             self._program_cache[sig] = (program, 0, None)
@@ -3418,7 +3422,7 @@ class MeshExecutor:
                 else:
                     data[out_name] = DictColumn(codes, d2)
             else:
-                data[out_name] = a.astype(host_dtype(dt))
+                data[out_name] = _f64_from_bits(a, dt)
         return RowBatch.from_pydict(
             m.out_relation, data, eow=True, eos=True
         )
@@ -3549,7 +3553,7 @@ class MeshExecutor:
                 else:
                     data[out_name] = DictColumn(codes, d2)
             else:
-                data[out_name] = a.astype(host_dtype(dt))
+                data[out_name] = _f64_from_bits(a, dt)
         return RowBatch.from_pydict(
             m.out_relation, data, eow=True, eos=True
         )
@@ -3591,7 +3595,7 @@ class MeshExecutor:
             n = int(np.count_nonzero(sel))
         if n != n_expect or len(kk) != n:
             return None, None  # table moved under us
-        cols = {c: np.asarray(a)[perm] for c, a in cols.items()}
+        cols = _f64_as_bits({c: np.asarray(a)[perm] for c, a in cols.items()})
         gids = kk[perm].astype(np.int32)
 
         def _do():
@@ -3746,14 +3750,14 @@ class MeshExecutor:
             n_sharded = len(l_names) + 2 + len(r_names) + 2
             n_repl = 1 + (1 if l_narrow else 0) + (1 if r_narrow else 0)
             program = jax.jit(
-                shard_map(
+                jax.shard_map(
                     shard_fn,
                     mesh=self.mesh,
                     in_specs=tuple(
                         [P(axes)] * n_sharded + [P()] * n_repl
                     ),
                     out_specs=tuple([P(axes[0])] * len(out_plan)),
-                    **_SM_CHECK_KW,
+                    check_vma=False,
                 )
             )
             self._program_cache[sig] = (program, 0, None)
@@ -3944,6 +3948,7 @@ class MeshExecutor:
         extra_cols=None,
         f32_cols=None,
         row_sel=None,
+        f64_bits=False,
     ):
         """Cache-or-stage with the shared OOM clear-and-retry policy.
         Returns the StagedColumns (staged.num_rows tells callers what the
@@ -3955,7 +3960,8 @@ class MeshExecutor:
         selection applies after the read (boolean-mask indexing keeps
         original row order, matching the host FilterNode), the mask
         length doubling as the table-moved race guard; ``key_plan``
-        gids are the caller's FILTERED encoding."""
+        gids are the caller's FILTERED encoding. ``f64_bits`` stages
+        float64 columns as their bit patterns (_f64_as_bits)."""
         staged = self._staged_lookup(cache_key)
         if staged is not None:
             return staged
@@ -3977,7 +3983,9 @@ class MeshExecutor:
             cols[name] = arr
         if key_plan.host_gids is not None and len(key_plan.host_gids) != n:
             return None
-        if not extra_cols and row_sel is None:
+        if f64_bits:
+            cols = _f64_as_bits(cols)
+        elif not extra_cols and row_sel is None:
             # Resident-ingest fast path (r13): assemble the staging from
             # HBM ring windows + a compressed cold tail — the scan/join
             # analogue of the stream loop's per-window substitution.
@@ -4204,12 +4212,12 @@ class MeshExecutor:
         in_specs = tuple([P(axis)] * n_sharded + [P()] * n_repl)
         out_specs = tuple([P(axis)] * (1 + len(jdtypes)))
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 shard_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
-                **_SM_CHECK_KW,
+                check_vma=False,
             )
         )
 
@@ -5750,12 +5758,12 @@ class MeshExecutor:
         )
         in_specs = tuple([P(axis)] * n_sharded + [P()] * n_repl)
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 shard_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=P(),
-                **_SM_CHECK_KW,
+                check_vma=False,
             )
         )
 
@@ -5880,12 +5888,12 @@ class MeshExecutor:
         in_specs = tuple([P(axis)] * n_sharded + [P()] * n_repl)
         out_specs = tuple([P(axis)] * n_state_leaves)
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 shard_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
-                **_SM_CHECK_KW,
+                check_vma=False,
             )
         )
 
@@ -5911,12 +5919,12 @@ class MeshExecutor:
         in_specs = tuple([P(axis)] * n_state_leaves)
         out_specs = tuple([P()] * n_state_leaves)
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 shard_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
-                **_SM_CHECK_KW,
+                check_vma=False,
             )
         )
 
@@ -6738,12 +6746,12 @@ class MeshExecutor:
         in_specs = tuple([P(axis)] * n_sharded + [P()] * n_repl)
         out_specs = tuple([P(axis)] * n_state_leaves)
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 shard_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
-                **_SM_CHECK_KW,
+                check_vma=False,
             )
         )
 

@@ -121,7 +121,9 @@ def register(r: Registry) -> None:
             # the pipeline only routes INT64 columns here, so the LUT
             # hashes exactly like the row path's raw values.
             cell_update=hll.cell_update,
-            finalize=lambda st: jnp.round(hll.estimate(st)).astype(jnp.int64),
+            finalize=hll.estimate,
+            device_finalize=hll.estimate_terms,
+            format_output=hll.estimate_from_terms,
             merge_kind=MergeKind.PMAX,
             doc=(
                 "Approximate distinct count via HyperLogLog "

@@ -292,3 +292,48 @@ def test_device_join_staged_sides_accounted(mesh, flagset):
     cd.execute_query(q)
     assert len(cd.device_executor._program_cache) == n_programs
     assert not cd.device_executor.fallback_errors
+
+
+def test_device_join_f64_payload_bit_exact(mesh, flagset):
+    """f64 payload rides the device as int64 bit patterns (the TPU's
+    emulated f64 changed low mantissa bits of gathered values): signs,
+    -0.0, infinities, NaN, subnormals and full-precision values all come
+    back with the host engine's exact bits."""
+    flagset("device_join_min_rows", 0)
+    special = np.array(
+        [-0.0, np.inf, -np.inf, np.nan, 5e-324, -1.7976931348623157e308,
+         19846197.675742373]
+    )
+
+    def build(ex):
+        c = build_carnot(ex)
+        rng = np.random.default_rng(11)
+        n = 512
+        lat = rng.normal(0.0, 1e6, n)
+        lat[: special.size] = special
+        svc = rng.choice([f"s{i}" for i in range(18)], n).astype(object)
+        svc[: special.size] = "s12"  # a key the right side holds
+        t = c.table_store.create_table("lhs2", REL_L)
+        t.write_pydict(
+            {
+                "time_": np.arange(n, dtype=np.int64) * 10,
+                "svc": svc,
+                "code": rng.choice([1, 2, 3], n),
+                "lat": lat,
+            }
+        )
+        t.compact()
+        t.stop()
+        return c
+
+    q = _join_query("inner").replace("table='lhs'", "table='lhs2'")
+    cd = build(MeshExecutor(mesh=mesh, block_rows=512))
+    rows_d = cd.execute_query(q).table("out")
+    rows_h = build(None).execute_query(q).table("out")
+    assert any(
+        s.startswith("join|") for s in cd.device_executor._program_cache
+    ), "join did not offload"
+    assert not cd.device_executor.fallback_errors
+    bits = lambda rows: np.asarray(rows["lat"], np.float64).view(np.int64)
+    np.testing.assert_array_equal(bits(rows_d), bits(rows_h))
+    assert set(special.view(np.int64)) <= set(bits(rows_d))

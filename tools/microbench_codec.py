@@ -117,7 +117,6 @@ def bench_family(mesh, name, arr, d, nblk, b, reps=3) -> dict:
 def main() -> int:
     import jax
 
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
     from jax.sharding import Mesh
 
     import pixie_tpu  # noqa: F401  (enables x64)

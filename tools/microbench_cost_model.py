@@ -180,9 +180,6 @@ def record_cost_model_detail(summary: dict, path: str = None) -> None:
 
 
 def main() -> int:
-    import jax
-
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
     import pixie_tpu  # noqa: F401  (enables x64)
 
     rows = int(os.environ.get("MB_CM_ROWS", 120_000))

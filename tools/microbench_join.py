@@ -75,7 +75,6 @@ def host_inner_join(bk, bv, pk, pv, nkeys):
 def main() -> int:
     import jax
 
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
 
     import pixie_tpu  # noqa: F401  (enables x64)
     import jax.numpy as jnp

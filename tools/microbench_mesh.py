@@ -331,18 +331,14 @@ def record_mesh_detail(summary: dict, path: str = None) -> None:
 
 
 def main() -> int:
-    # The hosts axis needs a pool to split: force 8 virtual CPU devices
-    # BEFORE the backend initializes (no-op when already configured or
-    # on a real multi-device platform).
-    if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
+    # The hosts axis needs a pool to split: on the CPU backend
+    # (JAX_PLATFORMS=cpu) force 8 virtual devices BEFORE it initializes.
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
         xf = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in xf:
             os.environ["XLA_FLAGS"] = (
                 xf + " --xla_force_host_platform_device_count=8"
             )
-    import jax
-
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
     import pixie_tpu  # noqa: F401  (enables x64)
 
     rows = int(os.environ.get("MB_MESH_ROWS", 200_000))
