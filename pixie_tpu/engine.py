@@ -294,28 +294,26 @@ class Carnot:
         # trace shape the broker path does, rooted at the query_id. When
         # an ambient context exists (an agent executing a broker plan
         # calls execute_plan directly), this path is not taken.
-        root = trace.begin(
+        root = trace.span(
             "query", trace_id=qid, parent_id="", instance=self.instance
         )
         t0 = time.perf_counter_ns()
         # r15: a standalone engine attributes its own CPU/device work to
         # the query (the broker/agent paths set their own attribution).
-        with trace.attribution(qid, "default", "query"):
-            with trace.context_of(root):
-                with trace.span("compile", instance=self.instance):
-                    plan = self.compiler.compile(
-                        query,
-                        self.table_store.relation_map(),
-                        now_ns=now_ns,
-                        script_args=script_args,
-                        query_id=qid,
-                        exec_funcs=exec_funcs,
-                    )
-                compile_ns = time.perf_counter_ns() - t0
-                result = self.execute_plan(plan, analyze=analyze)
+        with trace.attribution(qid, "default", "query"), root:
+            with trace.span("compile", instance=self.instance):
+                plan = self.compiler.compile(
+                    query,
+                    self.table_store.relation_map(),
+                    now_ns=now_ns,
+                    script_args=script_args,
+                    query_id=qid,
+                    exec_funcs=exec_funcs,
+                )
+            compile_ns = time.perf_counter_ns() - t0
+            result = self.execute_plan(plan, analyze=analyze)
         result.compile_time_ns = compile_ns
-        if root is not None:
-            trace.finish(root)
+        if root.span is not None:
             result.trace_spans = sorted(
                 (s.to_dict() for s in trace.spans_for(qid)),
                 key=lambda s: s["start_unix_ns"],
@@ -436,8 +434,10 @@ class Carnot:
                             frag = _splice_inline_source(
                                 frag, agg_nid, key, rel
                             )
-                    graph = ExecutionGraph(frag, state)
-                    graph.execute()
+                    # The host exec graph over the device result.
+                    with trace.span("exec", instance=self.instance):
+                        graph = ExecutionGraph(frag, state)
+                        graph.execute()
                     if analyze:
                         for name, s in graph.stats().items():
                             exec_stats[f"f{frag.fragment_id}/{name}"] = s

@@ -113,6 +113,11 @@ _BREAKER_SKIPS = _M.counter(
 _PROGRAMS = _M.gauge(
     "device_program_cache_size", "Compiled shard_map programs cached."
 )
+_AOT_PENDING = _M.gauge(
+    "device_aot_pending",
+    "Background (AOT) program compiles submitted and not yet finished, "
+    "over every executor in the process.",
+)
 _MESH_DEGRADE = _M.counter(
     "mesh_degrade_events_total",
     "Mesh geometry failures (host loss / hung collective) recovered by "
@@ -159,6 +164,7 @@ except Exception:  # pragma: no cover - monitoring API drift
 # layer); re-exported here for callers.
 from pixie_tpu.parallel.staging import (  # noqa: E402
     COLD_PROFILE,
+    count_read_batches,
     reset_cold_profile,
     timed as _timed,
 )
@@ -1217,10 +1223,15 @@ class MeshExecutor:
             }
         return out
 
+    def pending_compiles(self) -> int:
+        """Background (AOT) compiles submitted and not yet finished: 0
+        once every speculatively compiled program has landed."""
+        return sum(1 for f in list(self._aot_futures.values()) if not f.done())
+
     def health_snapshot(self) -> dict:
         """Device-executor health riding agent heartbeats (r10): breaker
         state per program key, open keys (what planning matches on),
-        background-compile queue depth, the last device-fold wall time,
+        pending background compiles, the last device-fold wall time,
         and (r11) per-program-key fold-latency percentiles."""
         snap = self.breaker_snapshot()
         return {
@@ -1228,7 +1239,7 @@ class MeshExecutor:
             "breaker_open": sorted(
                 k for k, v in snap.items() if v["state"] == "open"
             ),
-            "staging_depth": len(self._aot_futures),
+            "staging_depth": self.pending_compiles(),
             "last_fold_ms": self.last_fold_ms,
             "fold_latency": self.fold_latency_snapshot(),
             # HBM residency (r12): staged/pinned bytes vs hbm_budget_mb
@@ -1853,28 +1864,25 @@ class MeshExecutor:
             return None
         try:
             t0 = time.perf_counter_ns()
-            # r23: the fold runs under the geometry degradation ladder —
-            # a host loss or hung collective re-plans the same fold on
-            # the next surviving geometry (bit-identical) before the
-            # host engine is ever considered.
-            out = self._execute_with_recovery(
-                fragment, table_store, registry, func_ctx
-            )
+            # The whole device offload (stage hit/miss + fold + finalize)
+            # as one span; its phases (staging.timed) parent to it.
+            with trace.span(
+                "device.execute", attrs={"program_key": bkey[:120]}
+            ) as ex_span:
+                # r23: the fold runs under the geometry degradation
+                # ladder — a host loss or hung collective re-plans the
+                # same fold on the next surviving geometry
+                # (bit-identical) before the host engine is considered.
+                out = self._execute_with_recovery(
+                    fragment, table_store, registry, func_ctx
+                )
+                ex_span.set(offloaded=out is not None)
             (_OFFLOAD_HITS if out is not None else _OFFLOAD_MISS).inc()
             if out is not None:
                 self._breaker_record(bkey, ok=True)
                 elapsed_ns = time.perf_counter_ns() - t0
                 self.last_fold_ms = elapsed_ns / 1e6
                 self._record_fold_latency(bkey, self.last_fold_ms)
-                if trace.ACTIVE:
-                    # The whole device offload (stage hit/miss + fold +
-                    # finalize) as one span; per-phase children come from
-                    # the staging/stream profiling hooks.
-                    trace.record(
-                        "device.execute",
-                        elapsed_ns,
-                        attrs={"program_key": bkey[:120]},
-                    )
                 if resattr.ACTIVE:
                     # r15: the offload as one attributed dispatch row —
                     # joins device wall time to the ambient
@@ -1967,8 +1975,10 @@ class MeshExecutor:
         for out, e, uda in specs:
             if uda.reads_args and out not in any_candidates:
                 base_cols |= referenced_columns(e)
-        with _timed("plan_keys"):
-            key_plan = self._plan_keys(m, table, registry, func_ctx, base_cols)
+        with _timed("plan_keys") as sp:
+            key_plan = self._plan_keys(
+                m, table, registry, func_ctx, base_cols, sp
+            )
         if key_plan is None:
             return None
         base_groups = max(key_plan.num_groups, 1)
@@ -1977,7 +1987,10 @@ class MeshExecutor:
             # Window id = one more (leading) group axis: gid' = wid*G+gid,
             # windows cut at the cursor's eow markers — the same
             # boundaries the host AggNode emits on (agg_node.py:242).
-            wk = self._windowize_key_plan(m, table, key_plan, base_groups)
+            with _timed("windowize"):
+                wk = self._windowize_key_plan(
+                    m, table, key_plan, base_groups
+                )
             if wk is None:
                 return None
             key_plan, n_windows = wk
@@ -2161,14 +2174,6 @@ class MeshExecutor:
                         merged, capacity = self._run_program(
                             m, device_specs, evaluator, key_plan, staged, aux
                         )
-            elif flags.shared_scans and trace.ACTIVE:
-                # The stream path computed the fold during staging: no
-                # dispatch to share, but keep the span family uniform.
-                trace.record(
-                    "serving.shared_scan",
-                    0,
-                    attrs={"shared_scan_batch_size": 1, "role": "stream"},
-                )
             if (
                 self.fold_signature_store is not None
                 and staged is not None
@@ -2177,15 +2182,32 @@ class MeshExecutor:
                 self._record_fold_shape(
                     m, device_specs, key_plan, staged, capacity, aux
                 )
-            if m.agg_op.stage == AggStage.PARTIAL:
-                batch = self._partial_state_batch(
-                    m, device_specs, key_plan, merged, table
-                )
-            elif windowed:
-                # One RowBatch per window, eow-cadenced like the host
-                # AggNode.
-                batch = [
-                    self._finalize(
+            with _timed("finalize"):
+                if m.agg_op.stage == AggStage.PARTIAL:
+                    batch = self._partial_state_batch(
+                        m, device_specs, key_plan, merged, table
+                    )
+                elif windowed:
+                    # One RowBatch per window, eow-cadenced like the host
+                    # AggNode.
+                    batch = [
+                        self._finalize(
+                            m,
+                            specs,
+                            key_plan,
+                            capacity,
+                            merged,
+                            registry,
+                            table,
+                            host_any=host_any,
+                            group_range=(w * base_groups, base_groups),
+                            eow=True,
+                            eos=(w == n_windows - 1),
+                        )
+                        for w in range(n_windows)
+                    ]
+                else:
+                    batch = self._finalize(
                         m,
                         specs,
                         key_plan,
@@ -2194,23 +2216,7 @@ class MeshExecutor:
                         registry,
                         table,
                         host_any=host_any,
-                        group_range=(w * base_groups, base_groups),
-                        eow=True,
-                        eos=(w == n_windows - 1),
                     )
-                    for w in range(n_windows)
-                ]
-            else:
-                batch = self._finalize(
-                    m,
-                    specs,
-                    key_plan,
-                    capacity,
-                    merged,
-                    registry,
-                    table,
-                    host_any=host_any,
-                )
             return m.agg_nid, batch
 
     # -- device join-aggregate (inner join fused into the agg) ---------------
@@ -2470,7 +2476,7 @@ class MeshExecutor:
 
         if sig not in self._program_cache:
 
-            def shard_fn(*arrs):
+            def right_stats_fn(*arrs):
                 i = len(col_names)
                 cols = {n: a[0] for n, a in zip(col_names, arrs[:i])}
                 mask_all = arrs[i][0]
@@ -2550,7 +2556,7 @@ class MeshExecutor:
             n_out = 1 + len(stat_kinds)
             program = jax.jit(
                 jax.shard_map(
-                    shard_fn,
+                    right_stats_fn,
                     mesh=self.mesh,
                     in_specs=in_specs,
                     out_specs=tuple([P()] * n_out),
@@ -2660,7 +2666,7 @@ class MeshExecutor:
         )
         if sig not in self._program_cache:
 
-            def shard_fn(*arrs):
+            def join_left_fn(*arrs):
                 from pixie_tpu.ops import segment as _segment
 
                 i = len(col_names)
@@ -2783,7 +2789,7 @@ class MeshExecutor:
             in_specs = tuple([P(axis)] * n_sharded + [P()] * n_repl)
             program = jax.jit(
                 jax.shard_map(
-                    shard_fn,
+                    join_left_fn,
                     mesh=self.mesh,
                     in_specs=in_specs,
                     out_specs=P(),
@@ -3257,7 +3263,7 @@ class MeshExecutor:
         if sig not in self._program_cache:
             _segment.lane_count("join_sort_merge")
 
-            def shard_fn(*arrs):
+            def join_fn(*arrs):
                 i = len(l_names)
                 lcols = dict(zip(l_names, arrs[:i]))
                 lmask_b, lgids_b = arrs[i], arrs[i + 1]
@@ -3354,7 +3360,7 @@ class MeshExecutor:
             n_repl = 1 + (1 if l_narrow else 0) + (1 if r_narrow else 0)
             program = jax.jit(
                 jax.shard_map(
-                    shard_fn,
+                    join_fn,
                     mesh=self.mesh,
                     in_specs=tuple([P(axis)] * n_sharded + [P()] * n_repl),
                     out_specs=tuple([P()] * len(out_plan)),
@@ -3660,7 +3666,7 @@ class MeshExecutor:
         if sig not in self._program_cache:
             _segment.lane_count("join_partitioned")
 
-            def shard_fn(*arrs):
+            def partitioned_join_fn(*arrs):
                 i = len(l_names)
                 lcols = dict(zip(l_names, arrs[:i]))
                 lmask_b, lgids_b = arrs[i], arrs[i + 1]
@@ -3751,7 +3757,7 @@ class MeshExecutor:
             n_repl = 1 + (1 if l_narrow else 0) + (1 if r_narrow else 0)
             program = jax.jit(
                 jax.shard_map(
-                    shard_fn,
+                    partitioned_join_fn,
                     mesh=self.mesh,
                     in_specs=tuple(
                         [P(axes)] * n_sharded + [P()] * n_repl
@@ -4149,7 +4155,7 @@ class MeshExecutor:
         ]
         jdtypes = [jnp.dtype(dt) for dt in out_dtypes]
 
-        def shard_fn(*arrs):
+        def scan_fn(*arrs):
             i = len(col_names)
             cols = {n: a[0] for n, a in zip(col_names, arrs[:i])}
             mask_all = arrs[i][0]
@@ -4213,7 +4219,7 @@ class MeshExecutor:
         out_specs = tuple([P(axis)] * (1 + len(jdtypes)))
         return jax.jit(
             jax.shard_map(
-                shard_fn,
+                scan_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
@@ -4493,8 +4499,11 @@ class MeshExecutor:
         return specs
 
     def _plan_keys(
-        self, m: _Match, table, registry, func_ctx, base_cols: set
+        self, m: _Match, table, registry, func_ctx, base_cols: set, sp=None
     ) -> Optional[_KeyPlan]:
+        """The group-key plan; ``sp`` (the caller's device.plan_keys
+        span) gets ``batches`` (cursor batches evaluated) and ``cached``
+        (key-plan cache hit) when the generic host path runs."""
         groups = m.agg_op.groups
         if not groups:
             return _KeyPlan(device_expr=None, num_groups=1, key_columns=[])
@@ -4547,6 +4556,8 @@ class MeshExecutor:
         cached = self._keyplan_cache.get(kp_key) if kp_cacheable else None
         if cached is not None:
             self._keyplan_cache.move_to_end(kp_key)
+            if sp is not None:
+                sp.set(batches=0, cached=True)
             return cached
         key_refs = set()
         for g in groups:
@@ -4595,6 +4606,9 @@ class MeshExecutor:
                         out_dicts[g] = d
                 key_cols.append(col)
             gid_parts.append(enc.encode(key_cols))
+        count_read_batches(len(gid_parts))
+        if sp is not None:
+            sp.set(batches=len(gid_parts), cached=False)
         gids = (
             np.concatenate(gid_parts) if gid_parts else np.empty(0, np.int32)
         )
@@ -5026,6 +5040,9 @@ class MeshExecutor:
         # samples under that query's label.
         fut = self._aot_pool.submit(trace.attributed(work, phase="compile"))
         self._aot_futures[sig] = fut
+        gauge = _AOT_PENDING
+        gauge.inc()
+        fut.add_done_callback(lambda _f: gauge.dec())
         return fut
 
     def _aot_warm_fold(
@@ -5704,7 +5721,7 @@ class MeshExecutor:
             e for n, e in evaluator.named_exprs if n.startswith("pred")
         ]
 
-        def shard_fn(*arrs):
+        def fold_merge_fn(*arrs):
             # Layout: cols..., mask, [gids], [key_lut], aux...,
             # [narrow_offsets], gid_base. Sharded args arrive as
             # [1, nblk, B]; the rest are replicated; gid_base selects this
@@ -5759,7 +5776,7 @@ class MeshExecutor:
         in_specs = tuple([P(axis)] * n_sharded + [P()] * n_repl)
         return jax.jit(
             jax.shard_map(
-                shard_fn,
+                fold_merge_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=P(),
@@ -5837,7 +5854,7 @@ class MeshExecutor:
             e for n, e in evaluator.named_exprs if n.startswith("pred")
         ]
 
-        def shard_fn(*arrs):
+        def fold_fn(*arrs):
             # Layout: state leaves..., cols..., mask, [gids], [key_lut],
             # aux..., [narrow_offsets], gid_base.
             carry = jax.tree.unflatten(
@@ -5889,7 +5906,7 @@ class MeshExecutor:
         out_specs = tuple([P(axis)] * n_state_leaves)
         return jax.jit(
             jax.shard_map(
-                shard_fn,
+                fold_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
@@ -5905,7 +5922,7 @@ class MeshExecutor:
         axis = self.mesh_axes  # collectives reduce over the FULL mesh
         ndev = self.mesh.devices.size
 
-        def shard_fn(*arrs):
+        def merge_fn(*arrs):
             states, presence = jax.tree.unflatten(
                 treedef, [a[0] for a in arrs]
             )
@@ -5920,7 +5937,7 @@ class MeshExecutor:
         out_specs = tuple([P()] * n_state_leaves)
         return jax.jit(
             jax.shard_map(
-                shard_fn,
+                merge_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
@@ -5936,13 +5953,13 @@ class MeshExecutor:
         small unit while reusing the fold and merge executables."""
         fin_modes, _ = self._finalize_modes(specs, capacity, force_state)
 
-        def fn(*leaves):
+        def fin_fn(*leaves):
             states, presence = jax.tree.unflatten(treedef, leaves)
             return self._finalize_pack(
                 specs, fin_modes, list(states), presence
             )
 
-        return jax.jit(fn)
+        return jax.jit(fin_fn)
 
     def _stream_execute(
         self, m, specs, evaluator, key_plan, table, cols, n,
@@ -6142,15 +6159,9 @@ class MeshExecutor:
             fold_fn = fold_p
 
         def prof(key, dt):
+            # Per-window sums: the stream as a whole is the caller's
+            # device.stage span, so the span count stays per query.
             COLD_PROFILE[key] = COLD_PROFILE.get(key, 0.0) + dt
-            # r11: per-stream-window device phases join the query's span
-            # tree (pack/transfer/compile/fold per window) instead of
-            # living only in the COLD_PROFILE dict. Counter-valued keys
-            # (bytes, window counts) are not durations — skipped.
-            if trace.ACTIVE and key not in (
-                "stage_bytes", "wire_bytes", "stream_windows"
-            ):
-                trace.phase(f"device.{key}", dt)
 
         def resolve_fold(block: bool) -> bool:
             """Bind fold_fn once the AOT compile is available (or failed).
@@ -6687,7 +6698,7 @@ class MeshExecutor:
         device_key = key_plan.device_expr
         n_term = self._N_TERM_ARGS
 
-        def shard_fn(*arrs):
+        def batched_fold_fn(*arrs):
             # Layout: state leaves..., cols..., mask, [gids], [key_lut],
             # aux..., [narrow_offsets], term table (8), gid_base.
             carry = jax.tree.unflatten(
@@ -6747,7 +6758,7 @@ class MeshExecutor:
         out_specs = tuple([P(axis)] * n_state_leaves)
         return jax.jit(
             jax.shard_map(
-                shard_fn,
+                batched_fold_fn,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,

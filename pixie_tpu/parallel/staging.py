@@ -63,6 +63,13 @@ def reset_cold_profile() -> dict:
     return snap
 
 
+def count_read_batches(n: int) -> None:
+    """COLD_PROFILE["read_batches"]: table-cursor batches holding rows
+    that one pass over a table walked (each helper that walks a cursor
+    counts its own pass once)."""
+    COLD_PROFILE["read_batches"] = COLD_PROFILE.get("read_batches", 0.0) + n
+
+
 # Observed staged (decoded, HBM-resident) bytes per row, by table — the
 # metadata admission control uses to estimate a query's staging cost
 # BEFORE the cold stage starts (serving/admission.estimate_staging_bytes).
@@ -76,22 +83,29 @@ def record_observed_bpr(table_name: str, nbytes: int, rows: int) -> None:
 
 
 class timed:
-    """with timed('stage'): ... — accumulates into COLD_PROFILE, and
-    (r11) emits the same interval as a ``device.<key>`` trace span under
-    the running query's ambient context, so cold-path phase timings stop
-    being a bare dict and join the query's span tree."""
+    """with timed('stage') as sp: ... — accumulates into COLD_PROFILE, and
+    runs the block as a ``device.<key>`` span (utils/trace.py) under the
+    running query's ambient context: nested phases parent to it, and a
+    profiler trace shows it on the device's clock. ``sp.set(k=v)`` adds
+    span attributes. ``span=False`` for a phase that runs once a window
+    or a column: it only adds to COLD_PROFILE, inside its caller's span,
+    so that a query's span count does not grow with its data."""
 
-    def __init__(self, key: str):
+    def __init__(self, key: str, span: bool = True):
         self.key = key
+        self.span = trace.span(f"device.{key}") if span else None
 
     def __enter__(self):
+        if self.span is not None:
+            self.span.__enter__()
         self.t0 = time.perf_counter()
+        return self.span
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self.t0
         COLD_PROFILE[self.key] = COLD_PROFILE.get(self.key, 0.0) + dt
-        if trace.ACTIVE:
-            trace.phase(f"device.{self.key}", dt)
+        if self.span is not None:
+            self.span.__exit__(*exc)
         return False
 
 
@@ -203,6 +217,7 @@ def read_columns_windowed(
             break
         if b.num_rows or b.eow:
             batches.append(b)
+    count_read_batches(sum(1 for b in batches if b.num_rows))
     cols: dict[str, np.ndarray] = {}
     n = sum(b.num_rows for b in batches)
     for name in columns:
@@ -350,7 +365,7 @@ def stage_columns(
     narrow_offsets: dict[str, int] = {}
     blocks: dict[str, jax.Array] = {}
     for name, a in cols.items():
-        with timed("stage_host_pack"):
+        with timed("stage_host_pack", span=False):
             if f32_cols and name in f32_cols and a.dtype == np.float64:
                 a = a.astype(np.float32)
             else:
@@ -363,7 +378,7 @@ def stage_columns(
         # in HBM, bit-identical to the uncompressed transfer.
         payload = None
         if use_codec and num_rows > 0:
-            with timed("stage_encode"):
+            with timed("stage_encode", span=False):
                 cplan = _codec.plan_codec_local(
                     flat, d, nblk, b, num_rows,
                     codec_min_ratio(),
@@ -377,15 +392,15 @@ def stage_columns(
             "stage_bytes", 0.0
         ) + float(flat.nbytes)
         if payload is not None:
-            with timed("stage_transfer"):
+            with timed("stage_transfer", span=False):
                 args = _codec.put_payload(mesh, payload)
                 COLD_PROFILE["wire_bytes"] = COLD_PROFILE.get(
                     "wire_bytes", 0.0
                 ) + float(payload.nbytes)
-            with timed("stage_decode"):
+            with timed("stage_decode", span=False):
                 blocks[name] = _codec.decoder(mesh, cplan, nblk, b)(*args)
         else:
-            with timed("stage_transfer"):
+            with timed("stage_transfer", span=False):
                 # device_put is async on local backends; do NOT block per
                 # column — that serializes transfers behind each other and
                 # behind the next column's host pack. One sync below, after
@@ -397,7 +412,7 @@ def stage_columns(
                 COLD_PROFILE["wire_bytes"] = COLD_PROFILE.get(
                     "wire_bytes", 0.0
                 ) + float(flat.nbytes)
-    with timed("stage_transfer"):
+    with timed("stage_transfer", span=False):
         if blocks:
             jax.block_until_ready(list(blocks.values()))
     mask_dev = _build_mask(mesh, d, nblk, b, num_rows)
@@ -408,7 +423,7 @@ def stage_columns(
         if use_codec and num_rows > 0:
             # r16: the gids lane rides the codec like any value column —
             # sorted/low-churn group keys RLE to ~nothing.
-            with timed("stage_encode"):
+            with timed("stage_encode", span=False):
                 gplan = _codec.plan_codec_local(
                     gflat, d, nblk, b, num_rows,
                     codec_min_ratio(),
@@ -421,12 +436,12 @@ def stage_columns(
                     except _codec.CodecOverflow:
                         gpayload = None
         if gpayload is not None:
-            with timed("stage_transfer"):
+            with timed("stage_transfer", span=False):
                 gargs = _codec.put_payload(mesh, gpayload)
                 COLD_PROFILE["wire_bytes"] = COLD_PROFILE.get(
                     "wire_bytes", 0.0
                 ) + float(gpayload.nbytes)
-            with timed("stage_decode"):
+            with timed("stage_decode", span=False):
                 gids_dev = _codec.decoder(mesh, gplan, nblk, b)(*gargs)
         else:
             gids_dev = jax.device_put(
@@ -556,7 +571,7 @@ def stage_partitioned(
     narrow_offsets: dict[str, int] = {}
     blocks: dict[str, jax.Array] = {}
     for name, a in cols.items():
-        with timed("stage_host_pack"):
+        with timed("stage_host_pack", span=False):
             a, off = _narrow_int(np.asarray(a))
             if off is not None:
                 narrow_offsets[name] = off
@@ -564,14 +579,14 @@ def stage_partitioned(
         COLD_PROFILE["stage_bytes"] = COLD_PROFILE.get(
             "stage_bytes", 0.0
         ) + float(flat.nbytes)
-        with timed("stage_transfer"):
+        with timed("stage_transfer", span=False):
             blocks[name] = jax.device_put(flat.reshape(d, nblk, b), sharding)
             COLD_PROFILE["wire_bytes"] = COLD_PROFILE.get(
                 "wire_bytes", 0.0
             ) + float(flat.nbytes)
     gflat = scatter(_narrow_gids(np.asarray(gids), num_groups), 0)
     gids_dev = jax.device_put(gflat.reshape(d, nblk, b), sharding)
-    with timed("stage_transfer"):
+    with timed("stage_transfer", span=False):
         jax.block_until_ready(list(blocks.values()) + [gids_dev])
     mask_dev = _shard_mask_builder(mesh, d, nblk, b, region)(
         jnp.asarray(shard_rows)
@@ -810,7 +825,7 @@ def pack_stream_window(
     # correct — MeshExecutor.stream_fallback_errors records it).
     if faults.ACTIVE:
         faults.check("staging.pack")
-    with timed("stage_stream_pack"):
+    with timed("stage_stream_pack", span=False):
         lo = w * plan.window_rows
         hi = min(lo + plan.window_rows, plan.num_rows)
         rows = hi - lo
@@ -847,7 +862,7 @@ def pack_stream_window(
             if cp is not None:
                 flat = flat_pad(a, plan.block_dtypes[name])
                 try:
-                    with timed("stage_encode"):
+                    with timed("stage_encode", span=False):
                         packed[name] = _codec.encode_window(flat, cp, rows)
                     nbytes += packed[name].nbytes
                     continue
@@ -868,7 +883,7 @@ def pack_stream_window(
                     gids[lo:hi].astype(plan.gid_dtype), plan.gid_dtype
                 )
                 try:
-                    with timed("stage_encode"):
+                    with timed("stage_encode", span=False):
                         packed_gids = _codec.encode_window(
                             flat, plan.gid_codec, rows
                         )

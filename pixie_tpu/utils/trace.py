@@ -31,6 +31,12 @@ Design contract (mirrors utils/faults.py):
   transport frame) carries ``{"trace_id", "span_id"}`` explicitly and
   the far side re-enters the context with ``context(trace_id, span_id)``.
 
+- **One clock with the device.** Every with-block ``span()`` is also a
+  ``jax.profiler.TraceAnnotation`` of the span's own name, entered
+  whether or not tracing is on (~0.4 us a span with no profiler
+  running), so a profiler trace shows the program's spans on the
+  device's clock beside the device's operations.
+
 - **Finished spans are data.** ``Span.to_dict()`` is wire-encodable
   (str/int/dict only); agents ship their spans back on ``fragment_done``
   and the broker merges by span_id (in-process clusters share this
@@ -46,18 +52,20 @@ import time
 import uuid
 from typing import Any, Optional
 
+from jax.profiler import TraceAnnotation
+
 from pixie_tpu.utils.config import define_flag, flags
-from pixie_tpu.utils.metrics import metrics_registry
 
 define_flag(
     "query_tracing",
     True,
     help_="Distributed query tracing: every query gets a Dapper-style "
     "span tree covering broker, each participating agent, each exec "
-    "node, and per-window device stage/fold phases, assembled in "
+    "node, and the device offload's phases, assembled in "
     "QueryResult.profile and landed in the node's own query_spans table "
-    "(utils/trace.py). Off = spans are never created (<1% residual "
-    "overhead, gated by tools/microbench_fault_overhead.py).",
+    "(utils/trace.py). Off = spans are never recorded (<1% residual "
+    "overhead, gated by tools/microbench_fault_overhead.py); with-block "
+    "spans stay profiler annotations.",
 )
 define_flag(
     "trace_buffer_cap",
@@ -83,11 +91,6 @@ define_flag(
     "Off = attribution contexts and recorders are never entered (<1% "
     "residual cost, gated by tools/microbench_fault_overhead.py "
     "``profiler_overhead``).",
-)
-
-_SPAN_SECONDS = metrics_registry().histogram(
-    "span_duration_seconds",
-    "Finished trace-span durations by span name.",
 )
 
 # Fast gate read by every call site (one attribute load + branch when
@@ -375,7 +378,9 @@ def finish(
 class span:
     """``with trace.span("compile"): ...`` — an ambient child span: nested
     spans on this thread parent to it automatically. ``.set(k=v)`` adds
-    attributes; an exception propagating out marks status=error."""
+    attributes; an exception propagating out marks status=error. The
+    block is a profiler annotation of the same name even with tracing
+    off."""
 
     def __init__(
         self,
@@ -391,8 +396,10 @@ class span:
         self._instance = instance
         self._attrs = attrs
         self.span: Optional[Span] = None
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self.span = begin(
             self._name,
             trace_id=self._trace_id,
@@ -412,6 +419,7 @@ class span:
         if self.span is not None:
             _pop()
             finish(self.span, status="error" if exc_type else None)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -426,7 +434,7 @@ def record(
     attrs: Optional[dict] = None,
 ) -> Optional[Span]:
     """Buffer an already-measured span (exec-node stats, transport ack
-    latencies, device phase timings). Inherits the ambient context for
+    latencies). Inherits the ambient context for
     missing trace/parent ids; drops the span when tracing is off OR no
     trace context is resolvable (orphan phases outside any query)."""
     if not ACTIVE:
@@ -456,17 +464,9 @@ def record(
     return s
 
 
-def phase(name: str, duration_s: float, **attrs) -> None:
-    """Device/staging phase helper: a measured sub-span under the ambient
-    context (parallel/pipeline.py folds its COLD_PROFILE keys through
-    here, so per-window pack/transfer/compile/fold become spans)."""
-    record(name, int(duration_s * 1e9), attrs=attrs or None)
-
-
 def _record(s: Span) -> None:
     with _BUF_LOCK:
         _FINISHED.append(s)
-    _SPAN_SECONDS.observe(s.duration_ns / 1e9, name=s.name)
 
 
 # -- buffer access -----------------------------------------------------------

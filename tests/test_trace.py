@@ -580,6 +580,8 @@ def test_tracker_fold_latency_view_and_statusz(bus_cluster, monkeypatch):
             }
 
     agents[0].carnot.device_executor = DevStub()
+    # One query, so that the broker's latency histogram has a series.
+    assert broker.execute_script(AGG_QUERY, timeout_s=30).degraded is None
     _wait(
         lambda: "shape_x" in broker.tracker.fold_latency_view(),
         msg="fold latency never reached the tracker",
@@ -600,6 +602,6 @@ def test_tracker_fold_latency_view_and_statusz(bus_cluster, monkeypatch):
             .decode()
         )
         assert "broker_queries_total" in text
-        assert "span_duration_seconds_bucket" in text
+        assert "broker_query_seconds_bucket" in text
     finally:
         srv.stop()
