@@ -65,6 +65,7 @@ from pixie_tpu.plan.operators import (
 from pixie_tpu.plan.plan import PlanFragment
 from pixie_tpu.table.column import DictColumn, StringDictionary
 from pixie_tpu.table.row_batch import RowBatch
+from pixie_tpu.table.table import DEFAULT_COMPACTED_ROWS
 from pixie_tpu.types import DataType
 from pixie_tpu.types.dtypes import host_dtype
 from pixie_tpu.udf.udf import Executor, MergeKind
@@ -164,6 +165,7 @@ except Exception:  # pragma: no cover - monitoring API drift
 # layer); re-exported here for callers.
 from pixie_tpu.parallel.staging import (  # noqa: E402
     COLD_PROFILE,
+    count_key_evals,
     count_read_batches,
     reset_cold_profile,
     timed as _timed,
@@ -4502,8 +4504,9 @@ class MeshExecutor:
         self, m: _Match, table, registry, func_ctx, base_cols: set, sp=None
     ) -> Optional[_KeyPlan]:
         """The group-key plan; ``sp`` (the caller's device.plan_keys
-        span) gets ``batches`` (cursor batches evaluated) and ``cached``
-        (key-plan cache hit) when the generic host path runs."""
+        span) gets ``batches`` (cursor batches walked), ``evals`` (key
+        evaluations, one per chunk) and ``cached`` (key-plan cache hit)
+        when the generic host path runs."""
         groups = m.agg_op.groups
         if not groups:
             return _KeyPlan(device_expr=None, num_groups=1, key_columns=[])
@@ -4557,7 +4560,7 @@ class MeshExecutor:
         if cached is not None:
             self._keyplan_cache.move_to_end(kp_key)
             if sp is not None:
-                sp.set(batches=0, cached=True)
+                sp.set(batches=0, evals=0, cached=True)
             return cached
         key_refs = set()
         for g in groups:
@@ -4573,20 +4576,50 @@ class MeshExecutor:
         out_rel = MapOp(
             tuple((g, m.col_exprs[g]) for g in groups)
         ).output_relation([sub_rel], registry)
-        # Chunked first-touch pass: evaluate + densify per cursor batch
-        # instead of materializing the full key columns — at gigarow scale
-        # the monolithic evaluation was the cold-path's host-memory spike,
-        # and per-chunk np.unique is cheaper than one giant one
-        # (VERDICT r3 weakness 7). GroupEncoder assigns stable gids
-        # incrementally across chunks by construction.
+        # Chunked first-touch pass: the cursor's batches gather into chunks
+        # of exactly DEFAULT_COMPACTED_ROWS rows (the table's compacted
+        # batch size), and each chunk's keys are evaluated and densified
+        # in one pass. A compacted table's batches are already chunks; a
+        # run of small pushes pays one evaluation per chunk, not one per
+        # push. Host memory stays bounded by a chunk of key columns — at
+        # gigarow scale the monolithic evaluation was the cold-path's
+        # host-memory spike, and per-chunk np.unique is cheaper than one
+        # giant one (VERDICT r3 weakness 7). The last chunk is padded by
+        # repeating its last row, so an eager device UDF (px.bin) sees one
+        # shape whatever the span's row count. GroupEncoder assigns stable
+        # gids incrementally across chunks by construction.
+        chunk_rows = DEFAULT_COMPACTED_ROWS
         enc = GroupEncoder()
         gid_parts: list[np.ndarray] = []
         # Bare string columns keep the table's write-side dictionary, so
         # their codes are chunk-stable. COMPUTED string keys get a fresh
-        # dictionary per evaluated batch — re-encode those through one
+        # dictionary per evaluated chunk — re-encode those through one
         # stable dictionary or chunk codes would be incomparable.
         stable_dicts: dict[str, StringDictionary] = {}
         out_dicts: dict[str, StringDictionary] = {}
+
+        def densify(chunk: RowBatch, keep: int) -> None:
+            """Evaluate the keys of one chunk and encode its first
+            ``keep`` rows (the rest is padding)."""
+            key_batch = ev.evaluate(chunk, out_rel)
+            key_cols = []
+            for g, col in zip(groups, key_batch.columns):
+                if isinstance(col, DictColumn):
+                    col = col.slice(0, keep)
+                    if isinstance(m.col_exprs[g], ColumnRef):
+                        out_dicts[g] = col.dictionary
+                    else:
+                        d = stable_dicts.setdefault(g, StringDictionary())
+                        col = DictColumn(d.encode(col.decode()), d)
+                        out_dicts[g] = d
+                else:
+                    col = col[:keep]
+                key_cols.append(col)
+            gid_parts.append(enc.encode(key_cols))
+
+        batches = 0
+        pending: list[RowBatch] = []
+        pending_rows = 0
         cur = table.cursor(m.source_op.start_time, m.source_op.stop_time)
         while not cur.done():
             b = cur.next_batch()
@@ -4594,21 +4627,22 @@ class MeshExecutor:
                 break
             if not b.num_rows:
                 continue
-            key_batch = ev.evaluate(b.select(sub_names), out_rel)
-            key_cols = []
-            for g, col in zip(groups, key_batch.columns):
-                if isinstance(col, DictColumn):
-                    if isinstance(m.col_exprs[g], ColumnRef):
-                        out_dicts[g] = col.dictionary
-                    else:
-                        d = stable_dicts.setdefault(g, StringDictionary())
-                        col = DictColumn(d.encode(col.decode()), d)
-                        out_dicts[g] = d
-                key_cols.append(col)
-            gid_parts.append(enc.encode(key_cols))
-        count_read_batches(len(gid_parts))
+            batches += 1
+            pending.append(b.select(sub_names))
+            pending_rows += b.num_rows
+            while pending_rows >= chunk_rows:
+                buf = pending[0] if len(pending) == 1 else RowBatch.concat(pending)
+                densify(buf.slice(0, chunk_rows), chunk_rows)
+                rest = buf.slice(chunk_rows, pending_rows)
+                pending = [rest] if rest.num_rows else []
+                pending_rows = rest.num_rows
+        if pending_rows:
+            pad = np.minimum(np.arange(chunk_rows), pending_rows - 1)
+            densify(RowBatch.concat(pending).take(pad), pending_rows)
+        count_read_batches(batches)
+        count_key_evals(len(gid_parts))
         if sp is not None:
-            sp.set(batches=len(gid_parts), cached=False)
+            sp.set(batches=batches, evals=len(gid_parts), cached=False)
         gids = (
             np.concatenate(gid_parts) if gid_parts else np.empty(0, np.int32)
         )

@@ -70,6 +70,12 @@ def count_read_batches(n: int) -> None:
     COLD_PROFILE["read_batches"] = COLD_PROFILE.get("read_batches", 0.0) + n
 
 
+def count_key_evals(n: int) -> None:
+    """COLD_PROFILE["key_evals"]: group-key evaluations that one key
+    plan ran (one per fixed-size chunk of rows, MeshExecutor._plan_keys)."""
+    COLD_PROFILE["key_evals"] = COLD_PROFILE.get("key_evals", 0.0) + n
+
+
 # Observed staged (decoded, HBM-resident) bytes per row, by table — the
 # metadata admission control uses to estimate a query's staging cost
 # BEFORE the cold stage starts (serving/admission.estimate_staging_bytes).
