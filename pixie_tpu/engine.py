@@ -120,23 +120,28 @@ class QueryResult:
         return RowBatch.concat(batches).to_pydict()
 
 
-def _splice_inline_source(
-    fragment: PlanFragment, agg_nid: int, key: str, relation
-) -> PlanFragment:
-    """Replace the device-executed prefix (agg + its ancestors) with an
-    InlineSource emitting the computed aggregate, keeping the suffix."""
-    ancestors = set()
-    stack = list(fragment.parents(agg_nid))
-    while stack:
-        p = stack.pop()
-        if p not in ancestors:
-            ancestors.add(p)
-            stack.extend(fragment.parents(p))
+def _splice_inline_sources(fragment: PlanFragment, inline: dict) -> PlanFragment:
+    """Replace each device-executed aggregation (``inline``: agg nid ->
+    (key, relation)) with an InlineSource emitting its computed batches,
+    all in one splice, keeping the suffix. A node above them is dropped
+    only when no node that remains still reads it, so a fan-out's shared
+    prefix goes with its last branch."""
+    dropped: set = set()
+    for nid in reversed(fragment.topo_order()):
+        children = fragment.children(nid)
+        if nid not in inline and children and all(
+            c in inline or c in dropped for c in children
+        ):
+            dropped.add(nid)
     new = PlanFragment(fragment.fragment_id)
     mapping: dict[int, int] = {}
-    mapping[agg_nid] = new.add(InlineSourceOp(key=key, relation=relation))
-    for nid in fragment.topo_order():
-        if nid == agg_nid or nid in ancestors:
+    order = fragment.topo_order()
+    for nid in order:
+        if nid in inline:
+            key, relation = inline[nid]
+            mapping[nid] = new.add(InlineSourceOp(key=key, relation=relation))
+    for nid in order:
+        if nid in inline or nid in dropped:
             continue
         mapping[nid] = new.add(
             fragment.node(nid), [mapping[p] for p in fragment.parents(nid)]
@@ -412,28 +417,32 @@ class Carnot:
                             state.func_ctx,
                         )
                         if offloaded is not None:
-                            agg_nid, batch = offloaded
-                            key = f"device:{frag.fragment_id}:{agg_nid}"
-                            # Windowed device aggs return one batch PER
-                            # WINDOW (eow-cadenced, like the host AggNode).
-                            batches = (
-                                batch if isinstance(batch, list) else [batch]
-                            )
-                            state.inline_batches[key] = batches
-                            # StateBatches (PARTIAL offload) carry no
-                            # relation; resolve the agg op's declared
-                            # output instead.
-                            rel = getattr(batches[0], "relation", None)
-                            if rel is None:
-                                rel = frag.resolve_relations(
-                                    self.registry,
-                                    lambda op: self.table_store.get_relation(
-                                        op.table_name
-                                    ),
-                                )[agg_nid]
-                            frag = _splice_inline_source(
-                                frag, agg_nid, key, rel
-                            )
+                            inline = {}
+                            relations = None
+                            for agg_nid, batch in offloaded:
+                                key = f"device:{frag.fragment_id}:{agg_nid}"
+                                # Windowed device aggs return one batch PER
+                                # WINDOW (eow-cadenced, like the host
+                                # AggNode).
+                                batches = (
+                                    batch if isinstance(batch, list) else [batch]
+                                )
+                                state.inline_batches[key] = batches
+                                # StateBatches (PARTIAL offload) carry no
+                                # relation; resolve the agg op's declared
+                                # output instead.
+                                rel = getattr(batches[0], "relation", None)
+                                if rel is None:
+                                    if relations is None:
+                                        relations = frag.resolve_relations(
+                                            self.registry,
+                                            lambda op: self.table_store.get_relation(
+                                                op.table_name
+                                            ),
+                                        )
+                                    rel = relations[agg_nid]
+                                inline[agg_nid] = (key, rel)
+                            frag = _splice_inline_sources(frag, inline)
                     # The host exec graph over the device result.
                     with trace.span("exec", instance=self.instance):
                         graph = ExecutionGraph(frag, state)

@@ -6,6 +6,7 @@ stays the control/fallback engine, while fragments matching the hot shape
 
     MemorySource → (Map | Filter)* → Agg(FULL, not windowed)
 
+(or a fan-out of that chain into several such aggregations, staged once)
 compile into a single jit(shard_map(...)): each device lax.scans its shard
 of staged blocks, evaluating the fused projection/predicate expressions and
 updating UDA states via masked segment reductions; then one collective per
@@ -42,6 +43,7 @@ from pixie_tpu.parallel.staging import (
     _pow2_at_least,
     read_columns,
     stage_columns,
+    stage_gids,
 )
 from pixie_tpu.plan.expressions import (
     AggregateExpression,
@@ -165,6 +167,7 @@ except Exception:  # pragma: no cover - monitoring API drift
 # layer); re-exported here for callers.
 from pixie_tpu.parallel.staging import (  # noqa: E402
     COLD_PROFILE,
+    count_device_aggs,
     count_key_evals,
     count_read_batches,
     reset_cold_profile,
@@ -203,6 +206,26 @@ class _Match:
     source_relation: Any
 
 
+@dataclasses.dataclass
+class _Branch:
+    """One matched aggregation's device plan (MeshExecutor._plan_branch)."""
+
+    m: _Match
+    specs: list
+    evaluator: Any
+    windowed: bool
+    key_plan: Any
+    n_windows: int
+    base_groups: int
+    host_any: dict
+    device_specs: list
+    base_cols: set  # source columns it stages
+    cell_cols: dict
+    f32_cols: set
+    key_sig: str
+    cacheable: bool
+
+
 def match_fragment(fragment: PlanFragment, relations) -> Optional[_Match]:
     """Find the source→(map|filter)*→agg chain, composing expressions into
     source-column terms along the way."""
@@ -222,7 +245,22 @@ def match_fragment(fragment: PlanFragment, relations) -> Optional[_Match]:
             break
     if agg_nid is None:
         return None
-    # Walk up to the source.
+    path = _path_to_source(fragment, agg_nid)
+    if path is None:
+        return None
+    source_nid, chain = path
+    if any(len(fragment.children(n)) != 1 for n in [source_nid, *chain]):
+        # Shared with another branch: a fan-out of aggregations is
+        # match_fanout's; any other sharing is the host engine's job.
+        return None
+    if fragment.node(source_nid).streaming:
+        return None  # streaming stays with the live host cursor
+    return _compose_match(fragment, relations, source_nid, chain, agg_nid)
+
+
+def _path_to_source(fragment: PlanFragment, agg_nid: int):
+    """(source nid, [map/filter nids from the source down]) of the chain
+    that feeds ``agg_nid``, or None when it is not such a chain."""
     chain = []
     cur = agg_nid
     while True:
@@ -231,21 +269,21 @@ def match_fragment(fragment: PlanFragment, relations) -> Optional[_Match]:
             return None
         cur = parents[0]
         op = fragment.node(cur)
-        if len(fragment.children(cur)) != 1:
-            return None  # shared with another branch: host engine's job
         if isinstance(op, MemorySourceOp):
-            if op.streaming:
-                return None  # streaming stays with the live host cursor
-            source_nid = cur
-            break
+            return cur, chain[::-1]
         if not isinstance(op, (MapOp, FilterOp)):
             return None
-        chain.append(op)
-    chain.reverse()
+        chain.append(cur)
+
+
+def _compose_match(fragment, relations, source_nid, chain, agg_nid) -> _Match:
+    """Compose the chain's predicates and column expressions into
+    source-column terms."""
     source_rel = relations[source_nid]
     mapping = {c.name: ColumnRef(c.name) for c in source_rel}
     preds = []
-    for op in chain:
+    for nid in chain:
+        op = fragment.node(nid)
         if isinstance(op, FilterOp):
             preds.append(substitute(op.expr, mapping))
         else:
@@ -261,6 +299,45 @@ def match_fragment(fragment: PlanFragment, relations) -> Optional[_Match]:
         predicates=preds,
         source_relation=source_rel,
     )
+
+
+def match_fanout(fragment: PlanFragment, relations) -> Optional[list[_Match]]:
+    """The matches of every aggregation of a fan-out: one non-streaming
+    MemorySource whose (Map | Filter)* chain forks into two or more
+    branches, each (Map | Filter)* → Agg (not windowed). Every node with
+    more than one child must have only children that lead, through maps
+    and filters, to one of those aggregations. Any other shape (a branch
+    that displays raw rows, a node shared with a join, a streaming
+    source, a windowed aggregation) returns None: the host engine runs
+    the fragment whole. Each branch's composed terms include the shared
+    prefix."""
+    paths = {}
+    for nid in fragment.topo_order():
+        op = fragment.node(nid)
+        if not isinstance(op, AggOp) or op.windowed or op.stage not in (
+            AggStage.FULL,
+            AggStage.PARTIAL,
+        ):
+            continue
+        path = _path_to_source(fragment, nid)
+        if path is not None:
+            paths[nid] = path
+    sources = {src for src, _ in paths.values()}
+    if len(paths) < 2 or len(sources) != 1:
+        return None
+    (source_nid,) = sources
+    if fragment.node(source_nid).streaming:
+        return None
+    on_path = {source_nid, *paths}
+    for _, chain in paths.values():
+        on_path.update(chain)
+    for nid in on_path.difference(paths):
+        if any(c not in on_path for c in fragment.children(nid)):
+            return None
+    return [
+        _compose_match(fragment, relations, source_nid, chain, agg_nid)
+        for agg_nid, (_, chain) in paths.items()
+    ]
 
 
 # -- predicate normalization (r16; module-level since r20) -------------------
@@ -1847,12 +1924,18 @@ class MeshExecutor:
 
     def try_execute_fragment(
         self, fragment: PlanFragment, table_store, registry, func_ctx=None
-    ) -> Optional[tuple[int, RowBatch]]:
+    ) -> Optional[list[tuple[int, RowBatch]]]:
         """If the fragment contains the hot chain, run it on the mesh and
-        return (agg_node_id, finalized agg RowBatch); else None — including
-        when any stage of device planning/tracing fails (host-untraceable
-        expressions, dictionary edge cases): offload is an optimization,
-        never a correctness cliff.
+        return [(agg_node_id, finalized agg RowBatch)]; else None —
+        including when any stage of device planning/tracing fails
+        (host-untraceable expressions, dictionary edge cases): offload is
+        an optimization, never a correctness cliff.
+
+        A fan-out (one source chain forking into several aggregations,
+        see match_fanout) returns one pair per aggregation, all from one
+        staging of the union of their columns; if any branch cannot be
+        planned, none is offloaded and the host engine runs the whole
+        fragment. The single-chain lanes return a one-element list.
 
         Circuit breaker (r9): device_breaker_threshold consecutive
         failures for one program key skip the device entirely for
@@ -1878,9 +1961,13 @@ class MeshExecutor:
                 out = self._execute_with_recovery(
                     fragment, table_store, registry, func_ctx
                 )
-                ex_span.set(offloaded=out is not None)
+                ex_span.set(
+                    offloaded=out is not None,
+                    aggs=0 if out is None else len(out),
+                )
             (_OFFLOAD_HITS if out is not None else _OFFLOAD_MISS).inc()
             if out is not None:
+                count_device_aggs(len(out))
                 self._breaker_record(bkey, ok=True)
                 elapsed_ns = time.perf_counter_ns() - t0
                 self.last_fold_ms = elapsed_ns / 1e6
@@ -1916,35 +2003,32 @@ class MeshExecutor:
 
     def _try_execute_fragment(
         self, fragment: PlanFragment, table_store, registry, func_ctx=None
-    ) -> Optional[tuple[int, RowBatch]]:
+    ) -> Optional[list[tuple[int, RowBatch]]]:
         table_rel = lambda op: table_store.get_relation(op.table_name)
         relations = fragment.resolve_relations(registry, table_rel)
         m = match_fragment(fragment, relations)
-        if m is None:
-            ja = self._try_execute_join_agg(
-                fragment, relations, table_store, registry, func_ctx
-            )
-            if ja is not None:
-                return ja
-            # r19: join-agg decomposition first (it never materializes the
-            # pairs), then the standalone sort-merge join lane.
-            dj = self._try_execute_join(
-                fragment, relations, table_store, registry, func_ctx
-            )
-            if dj is not None:
-                return dj
-            return self._try_execute_scan(
-                fragment, relations, table_store, registry, func_ctx
-            )
-        table = table_store.get_table(m.source_op.table_name)
-        if table is None:
-            return None
-        # Fault site: poison the device fold dispatch for a matched
-        # fragment (chaos tests prove the fallback is bit-identical on the
-        # host engine and the circuit breaker trips after N hits).
-        if faults.ACTIVE:
-            faults.check("pipeline.fold")
+        if m is not None:
+            out = self._execute_match(m, table_store, registry, func_ctx)
+            return None if out is None else [out]
+        ms = match_fanout(fragment, relations)
+        if ms is not None:
+            return self._execute_fanout(ms, table_store, registry, func_ctx)
+        # r19: join-agg decomposition first (it never materializes the
+        # pairs), then the standalone sort-merge join lane, then the scan.
+        for lane in (
+            self._try_execute_join_agg,
+            self._try_execute_join,
+            self._try_execute_scan,
+        ):
+            out = lane(fragment, relations, table_store, registry, func_ctx)
+            if out is not None:
+                return [out]
+        return None
 
+    def _plan_branch(self, m: _Match, table, registry, func_ctx):
+        """One aggregation's device plan (its UDAs, expressions, key plan
+        and the columns it stages), or None when it cannot run on the
+        device."""
         specs = self._agg_specs(m, registry)
         if specs is None:
             return None
@@ -2009,7 +2093,6 @@ class MeshExecutor:
                 base_cols |= referenced_columns(e)
         device_specs = [s for s in specs if s[0] not in host_any]
         capacity_hint, _ = self._pass_plan(device_specs, key_plan.num_groups)
-        cell_cols = self._cell_cols(m, device_specs, capacity_hint)
         # The key signature must pin the actual group expressions — two
         # queries over the same table version with different groupbys must
         # not share staged gids.
@@ -2019,21 +2102,55 @@ class MeshExecutor:
             ":host" if key_plan.host_gids is not None
             else (":lut" if isinstance(key_plan.device_expr, tuple) else ":dev")
         ) + (f":win{n_windows}" if windowed else "")
+        return _Branch(
+            m=m,
+            specs=specs,
+            evaluator=evaluator,
+            windowed=windowed,
+            key_plan=key_plan,
+            n_windows=n_windows,
+            base_groups=base_groups,
+            host_any=host_any,
+            device_specs=device_specs,
+            base_cols=base_cols,
+            cell_cols=self._cell_cols(m, device_specs, capacity_hint),
+            # f32-staged sketch columns participate in the cache identity:
+            # an exact f64 aggregation must never reuse a staging narrowed
+            # for a sketch-only query (silently f32-truncated sums
+            # otherwise).
+            f32_cols=self._sketch_f32_cols(m, specs),
+            key_sig=key_sig,
+            # Staged HOST gids derived from mutable metadata state
+            # (needs_ctx UDFs) must never be cached — pod/service
+            # mappings churn without table writes. The device-LUT key
+            # path is safe: staged blocks hold raw codes and the LUT is
+            # recomputed and passed as an argument.
+            cacheable=key_plan.host_gids is None or not any(
+                _uses_ctx_func(m.col_exprs[g], m.source_relation, registry)
+                for g in m.agg_op.groups
+            ),
+        )
+
+    def _execute_match(self, m: _Match, table_store, registry, func_ctx):
+        """(agg nid, batch) of one source→(map|filter)*→agg chain."""
+        table = table_store.get_table(m.source_op.table_name)
+        if table is None:
+            return None
+        # Fault site: poison the device fold dispatch for a matched
+        # fragment (chaos tests prove the fallback is bit-identical on the
+        # host engine and the circuit breaker trips after N hits).
+        if faults.ACTIVE:
+            faults.check("pipeline.fold")
+        b = self._plan_branch(m, table, registry, func_ctx)
+        if b is None:
+            return None
+        specs, evaluator, key_plan = b.specs, b.evaluator, b.key_plan
+        device_specs, base_cols = b.device_specs, b.base_cols
+        cell_cols, f32_cols, cacheable = b.cell_cols, b.f32_cols, b.cacheable
+        windowed = b.windowed
         # Version = (min_row_id, end_row_id): writes bump end_row_id and
         # ring-buffer expiry bumps min_row_id, so either invalidates.
         version = (table.min_row_id(), table.end_row_id())
-        # f32-staged sketch columns participate in the cache identity: an
-        # exact f64 aggregation must never reuse a staging narrowed for a
-        # sketch-only query (silently f32-truncated sums otherwise).
-        f32_cols = self._sketch_f32_cols(m, specs)
-        # Staged HOST gids derived from mutable metadata state (needs_ctx
-        # UDFs) must never be cached — pod/service mappings churn without
-        # table writes. The device-LUT key path is safe: staged blocks hold
-        # raw codes and the LUT is recomputed and passed as an argument.
-        cacheable = key_plan.host_gids is None or not any(
-            _uses_ctx_func(m.col_exprs[g], m.source_relation, registry)
-            for g in m.agg_op.groups
-        )
         cache_key = (
             m.source_op.table_name,
             version,
@@ -2041,7 +2158,7 @@ class MeshExecutor:
             m.source_op.start_time,
             m.source_op.stop_time,
             self.block_rows,
-            key_sig,
+            b.key_sig,
             key_plan.num_groups,
             tuple(sorted(f32_cols)),
             # name AND cardinality bound: two queries with different
@@ -2065,23 +2182,7 @@ class MeshExecutor:
                     cache_key = k
                     staged = v
                     break
-        if staged is not None and not self._staged_mesh_ok(staged):
-            # Geometry changed since this entry staged (an r23
-            # degradation rung, or a half-open recovery back to full):
-            # re-place its shards onto the current mesh through the
-            # partition-rule tree — same bytes, no host restage. The
-            # old entry retires (zombie while a concurrent fold on the
-            # old mesh still pins it).
-            from pixie_tpu.parallel import staging as _staging_mod
-
-            with _timed("stage_repartition"):
-                staged = _staging_mod.repartition_staged(self.mesh, staged)
-            if cacheable:
-                self._staged_insert(
-                    cache_key, staged, m.source_op.table_name, version
-                )
-        if staged is not None:
-            self._staged_cache.touch(cache_key)
+        staged = self._staged_on_mesh(staged, cache_key, cacheable, version)
         merged = capacity = None
         if staged is None:
             with _timed("read_columns"):
@@ -2115,39 +2216,12 @@ class MeshExecutor:
                             cache_key, staged, m.source_op.table_name, version
                         )
             if merged is None:
-                int_dicts = {}
-                with _timed("int_dict_encode"):
-                    from pixie_tpu.parallel.staging import int_dict_encode
-
-                    for col, max_card in cell_cols.items():
-                        enc = int_dict_encode(cols[col], max_card)
-                        if enc is not None:
-                            cols[col], int_dicts[col] = enc
-                try:
-                    with _timed("stage"):
-                        staged = self._stage(
-                            cols, n, key_plan, table, f32_cols, int_dicts
-                        )
-                except Exception as e:
-                    if "RESOURCE_EXHAUSTED" not in str(e) and (
-                        "Out of memory" not in str(e)
-                    ):
-                        raise  # deterministic failures must not nuke the cache
-                    # Device OOM: drop every cached staging and retry once —
-                    # better than falling back to the host engine for a
-                    # gigarow table. (Entries pinned by concurrent folds
-                    # survive as accounted zombies; their memory was never
-                    # ours to free.)
-                    self._staged_cache.clear(reason="oom")
-                    staged = None
-                if staged is None:
-                    # Retry OUTSIDE the except block: the in-flight exception's
-                    # traceback pins the failed attempt's partially allocated
-                    # device buffers until the handler exits.
-                    with _timed("stage"):
-                        staged = self._stage(
-                            cols, n, key_plan, table, f32_cols, int_dicts
-                        )
+                int_dicts = self._int_dict_encode(cols, cell_cols)
+                staged = self._stage_or_clear(
+                    lambda: self._stage(
+                        cols, n, key_plan, table, f32_cols, int_dicts
+                    )
+                )
                 if cacheable:
                     self._staged_insert(
                         cache_key, staged, m.source_op.table_name, version
@@ -2164,18 +2238,7 @@ class MeshExecutor:
                         evaluator, m, key_plan, table, device_specs
                     )
                 with _timed("program"):
-                    if flags.shared_scans:
-                        # Shared scan (r12): coalesce with any concurrent
-                        # query whose fold signature + aux values match —
-                        # one device dispatch, per-query finalize below.
-                        merged, capacity = self._shared_scan_run(
-                            m, device_specs, evaluator, key_plan, staged,
-                            aux, cache_key,
-                        )
-                    else:
-                        merged, capacity = self._run_program(
-                            m, device_specs, evaluator, key_plan, staged, aux
-                        )
+                    merged, capacity = self._fold(b, staged, aux, cache_key)
             if (
                 self.fold_signature_store is not None
                 and staged is not None
@@ -2185,41 +2248,238 @@ class MeshExecutor:
                     m, device_specs, key_plan, staged, capacity, aux
                 )
             with _timed("finalize"):
-                if m.agg_op.stage == AggStage.PARTIAL:
-                    batch = self._partial_state_batch(
-                        m, device_specs, key_plan, merged, table
-                    )
-                elif windowed:
-                    # One RowBatch per window, eow-cadenced like the host
-                    # AggNode.
-                    batch = [
-                        self._finalize(
-                            m,
-                            specs,
-                            key_plan,
-                            capacity,
-                            merged,
-                            registry,
-                            table,
-                            host_any=host_any,
-                            group_range=(w * base_groups, base_groups),
-                            eow=True,
-                            eos=(w == n_windows - 1),
-                        )
-                        for w in range(n_windows)
-                    ]
-                else:
-                    batch = self._finalize(
-                        m,
-                        specs,
-                        key_plan,
-                        capacity,
-                        merged,
-                        registry,
-                        table,
-                        host_any=host_any,
-                    )
+                batch = self._finalize_branch(
+                    b, merged, capacity, registry, table
+                )
             return m.agg_nid, batch
+
+    def _execute_fanout(self, ms: list, table_store, registry, func_ctx):
+        """[(agg nid, batch)] of every branch of a fan-out (match_fanout):
+        the union of the branches' columns staged once, under one cache
+        entry, and each branch's own predicates, key plan, fold and
+        finalize run over it. None (the host engine runs the fragment)
+        when any branch cannot be planned."""
+        src = ms[0].source_op
+        table = table_store.get_table(src.table_name)
+        if table is None:
+            return None
+        if faults.ACTIVE:
+            faults.check("pipeline.fold")
+        branches = []
+        for m in ms:
+            b = self._plan_branch(m, table, registry, func_ctx)
+            if b is None:
+                return None  # never offload half a fan-out
+            branches.append(b)
+        cols_all = set().union(*(b.base_cols for b in branches))
+
+        def readers(col):
+            return [b for b in branches if col in b.base_cols]
+
+        # A column narrows (f32 sketch staging, int-dictionary cell lane)
+        # only when every branch that reads it would narrow it alone.
+        f32_cols = {
+            c
+            for c in set().union(*(b.f32_cols for b in branches))
+            if all(c in b.f32_cols for b in readers(c))
+        }
+        cell_cols = {
+            c: min(b.cell_cols[c] for b in readers(c))
+            for c in set().union(*(b.cell_cols for b in branches))
+            if all(c in b.cell_cols for b in readers(c))
+        }
+        cacheable = all(b.cacheable for b in branches)
+        version = (table.min_row_id(), table.end_row_id())
+        cache_key = (
+            src.table_name,
+            version,
+            tuple(sorted(cols_all)),
+            src.start_time,
+            src.stop_time,
+            self.block_rows,
+            tuple(b.key_sig for b in branches),
+            tuple(b.key_plan.num_groups for b in branches),
+            tuple(sorted(f32_cols)),
+            tuple(sorted(cell_cols.items())),
+        )
+        staged = self._staged_cache.get(cache_key) if cacheable else None
+        staged = self._staged_on_mesh(staged, cache_key, cacheable, version)
+        if staged is None:
+            with _timed("read_columns"):
+                cols, n = read_columns(
+                    table, sorted(cols_all), src.start_time, src.stop_time
+                )
+            if any(
+                b.key_plan.host_gids is not None
+                and len(b.key_plan.host_gids) != n
+                for b in branches
+            ):
+                return None  # table moved under us; fall back
+            int_dicts = self._int_dict_encode(cols, cell_cols)
+
+            def stage():
+                union = stage_columns(
+                    self.mesh,
+                    cols,
+                    n,
+                    dictionaries=table.dictionaries,
+                    block_rows=self.block_rows,
+                    f32_cols=f32_cols,
+                    int_dicts=int_dicts,
+                )
+                union.branch_gids = {
+                    b.m.agg_nid: stage_gids(
+                        self.mesh,
+                        b.key_plan.host_gids,
+                        n,
+                        max(b.key_plan.num_groups, 1),
+                        self.block_rows,
+                    )
+                    for b in branches
+                    if b.key_plan.host_gids is not None
+                }
+                return union
+
+            staged = self._stage_or_clear(stage)
+            if cacheable:
+                self._staged_insert(cache_key, staged, src.table_name, version)
+        out = []
+        with self._staged_cache.pin(cache_key if cacheable else None):
+            for b in branches:
+                nid = b.m.agg_nid
+                view = dataclasses.replace(
+                    staged,
+                    gids=staged.branch_gids.get(nid),
+                    num_groups=max(b.key_plan.num_groups, 1),
+                    capacity=_pow2_at_least(max(b.key_plan.num_groups, 1)),
+                    key_columns=list(b.key_plan.key_columns),
+                    branch_gids={},
+                )
+                with _timed("aux"):
+                    aux = self._build_aux(
+                        b.evaluator, b.m, b.key_plan, table, b.device_specs
+                    )
+                with _timed("program") as sp:
+                    sp.set(agg=nid)
+                    merged, capacity = self._fold(
+                        b, view, aux, (cache_key, nid)
+                    )
+                with _timed("finalize") as sp:
+                    sp.set(agg=nid)
+                    out.append(
+                        (
+                            nid,
+                            self._finalize_branch(
+                                b, merged, capacity, registry, table
+                            ),
+                        )
+                    )
+        return out
+
+    def _staged_on_mesh(self, staged, cache_key, cacheable, version):
+        """``staged`` on the executor's current mesh, LRU-touched."""
+        if staged is not None and not self._staged_mesh_ok(staged):
+            # Geometry changed since this entry staged (an r23
+            # degradation rung, or a half-open recovery back to full):
+            # re-place its shards onto the current mesh through the
+            # partition-rule tree — same bytes, no host restage. The
+            # old entry retires (zombie while a concurrent fold on the
+            # old mesh still pins it).
+            from pixie_tpu.parallel import staging as _staging_mod
+
+            with _timed("stage_repartition"):
+                staged = _staging_mod.repartition_staged(self.mesh, staged)
+            if cacheable:
+                self._staged_insert(cache_key, staged, cache_key[0], version)
+        if staged is not None:
+            self._staged_cache.touch(cache_key)
+        return staged
+
+    @staticmethod
+    def _int_dict_encode(cols: dict, cell_cols: dict) -> dict:
+        """Replace each cell-lane column of ``cols`` that fits its bound
+        by its small-domain codes; returns {column: value LUT}."""
+        from pixie_tpu.parallel.staging import int_dict_encode
+
+        int_dicts = {}
+        with _timed("int_dict_encode"):
+            for col, max_card in cell_cols.items():
+                enc = int_dict_encode(cols[col], max_card)
+                if enc is not None:
+                    cols[col], int_dicts[col] = enc
+        return int_dicts
+
+    def _stage_or_clear(self, stage):
+        """``stage()`` under the ``stage`` phase; on a device OOM, drop
+        every cached staging and stage once more — better than falling
+        back to the host engine for a gigarow table. (Entries pinned by
+        concurrent folds survive as accounted zombies; their memory was
+        never ours to free.)"""
+        try:
+            with _timed("stage"):
+                return stage()
+        except Exception as e:
+            if "RESOURCE_EXHAUSTED" not in str(e) and (
+                "Out of memory" not in str(e)
+            ):
+                raise  # deterministic failures must not nuke the cache
+            self._staged_cache.clear(reason="oom")
+        # Retry OUTSIDE the except block: the in-flight exception's
+        # traceback pins the failed attempt's partially allocated device
+        # buffers until the handler exits.
+        with _timed("stage"):
+            return stage()
+
+    def _fold(self, b, staged, aux, cache_key):
+        """(merged, capacity) of branch ``b``'s fold over ``staged``."""
+        if flags.shared_scans:
+            # Shared scan (r12): coalesce with any concurrent query whose
+            # fold signature + aux values match — one device dispatch,
+            # per-query finalize.
+            return self._shared_scan_run(
+                b.m, b.device_specs, b.evaluator, b.key_plan, staged, aux,
+                cache_key,
+            )
+        return self._run_program(
+            b.m, b.device_specs, b.evaluator, b.key_plan, staged, aux
+        )
+
+    def _finalize_branch(self, b, merged, capacity, registry, table):
+        """Branch ``b``'s output: raw states (PARTIAL), one RowBatch per
+        window (windowed, eow-cadenced like the host AggNode), or one
+        RowBatch."""
+        m, key_plan = b.m, b.key_plan
+        if m.agg_op.stage == AggStage.PARTIAL:
+            return self._partial_state_batch(
+                m, b.device_specs, key_plan, merged, table
+            )
+        if b.windowed:
+            return [
+                self._finalize(
+                    m,
+                    b.specs,
+                    key_plan,
+                    capacity,
+                    merged,
+                    registry,
+                    table,
+                    host_any=b.host_any,
+                    group_range=(w * b.base_groups, b.base_groups),
+                    eow=True,
+                    eos=(w == b.n_windows - 1),
+                )
+                for w in range(b.n_windows)
+            ]
+        return self._finalize(
+            m,
+            b.specs,
+            key_plan,
+            capacity,
+            merged,
+            registry,
+            table,
+            host_any=b.host_any,
+        )
 
     # -- device join-aggregate (inner join fused into the agg) ---------------
     def _try_execute_join_agg(

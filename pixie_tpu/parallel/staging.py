@@ -76,6 +76,12 @@ def count_key_evals(n: int) -> None:
     COLD_PROFILE["key_evals"] = COLD_PROFILE.get("key_evals", 0.0) + n
 
 
+def count_device_aggs(n: int) -> None:
+    """COLD_PROFILE["device_aggs"]: aggregations that one offload
+    answered on the device (one per branch of a fan-out)."""
+    COLD_PROFILE["device_aggs"] = COLD_PROFILE.get("device_aggs", 0.0) + n
+
+
 # Observed staged (decoded, HBM-resident) bytes per row, by table — the
 # metadata admission control uses to estimate a query's staging cost
 # BEFORE the cold stage starts (serving/admission.estimate_staging_bytes).
@@ -140,6 +146,10 @@ class StagedColumns:
     # (uint8/uint16) and int_dicts[name] is the [C] int64 value LUT — the
     # cell lane aggregates per (group, code) histogram instead of per row.
     int_dicts: dict = dataclasses.field(default_factory=dict)
+    # A fan-out's staging (one table staged for several aggregations):
+    # the gid blocks of each branch that groups by host gids, by the
+    # branch's aggregation node id. ``gids`` is then None.
+    branch_gids: dict = dataclasses.field(default_factory=dict)
 
 
 def _pow2_at_least(n: int, floor: int = 8) -> int:
@@ -422,41 +432,14 @@ def stage_columns(
         if blocks:
             jax.block_until_ready(list(blocks.values()))
     mask_dev = _build_mask(mesh, d, nblk, b, num_rows)
-    gids_dev = None
-    if gids is not None:
-        gflat = flat_pad(_narrow_gids(gids, num_groups), 0)
-        gpayload = None
-        if use_codec and num_rows > 0:
-            # r16: the gids lane rides the codec like any value column —
-            # sorted/low-churn group keys RLE to ~nothing.
-            with timed("stage_encode", span=False):
-                gplan = _codec.plan_codec_local(
-                    gflat, d, nblk, b, num_rows,
-                    codec_min_ratio(),
-                )
-                if gplan is not None:
-                    try:
-                        gpayload = _codec.encode_window(
-                            gflat, gplan, num_rows
-                        )
-                    except _codec.CodecOverflow:
-                        gpayload = None
-        if gpayload is not None:
-            with timed("stage_transfer", span=False):
-                gargs = _codec.put_payload(mesh, gpayload)
-                COLD_PROFILE["wire_bytes"] = COLD_PROFILE.get(
-                    "wire_bytes", 0.0
-                ) + float(gpayload.nbytes)
-            with timed("stage_decode", span=False):
-                gids_dev = _codec.decoder(mesh, gplan, nblk, b)(*gargs)
-        else:
-            gids_dev = jax.device_put(
-                gflat.reshape(d, nblk, b), sharding
-            )
     return StagedColumns(
         blocks=blocks,
         mask=mask_dev,
-        gids=gids_dev,
+        gids=(
+            None
+            if gids is None
+            else stage_gids(mesh, gids, num_rows, num_groups, block_rows)
+        ),
         num_rows=num_rows,
         num_devices=d,
         block_rows=b,
@@ -467,6 +450,48 @@ def stage_columns(
         narrow_offsets=narrow_offsets,
         int_dicts=dict(int_dicts or {}),
     )
+
+
+def stage_gids(
+    mesh: Mesh,
+    gids: np.ndarray,
+    num_rows: int,
+    num_groups: int,
+    block_rows: int = DEFAULT_BLOCK_ROWS,
+) -> jax.Array:
+    """Dense gids as [D, nblk, B] device blocks, at the geometry that
+    stage_columns gives ``num_rows`` rows."""
+    from pixie_tpu.ops import codec as _codec
+
+    d = mesh.devices.size
+    b, nblk = block_geometry(num_rows, d, block_rows)
+    narrow = _narrow_gids(gids, num_groups)
+    gflat = np.zeros(d * nblk * b, narrow.dtype if narrow.size else np.int32)
+    gflat[:num_rows] = narrow
+    gpayload = None
+    if flags.staging_codec and num_rows > 0:
+        # r16: the gids lane rides the codec like any value column —
+        # sorted/low-churn group keys RLE to ~nothing.
+        with timed("stage_encode", span=False):
+            gplan = _codec.plan_codec_local(
+                gflat, d, nblk, b, num_rows,
+                codec_min_ratio(),
+            )
+            if gplan is not None:
+                try:
+                    gpayload = _codec.encode_window(gflat, gplan, num_rows)
+                except _codec.CodecOverflow:
+                    gpayload = None
+    if gpayload is not None:
+        with timed("stage_transfer", span=False):
+            gargs = _codec.put_payload(mesh, gpayload)
+            COLD_PROFILE["wire_bytes"] = COLD_PROFILE.get(
+                "wire_bytes", 0.0
+            ) + float(gpayload.nbytes)
+        with timed("stage_decode", span=False):
+            return _codec.decoder(mesh, gplan, nblk, b)(*gargs)
+    sharding = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    return jax.device_put(gflat.reshape(d, nblk, b), sharding)
 
 
 def repartition_staged(mesh: Mesh, staged: StagedColumns) -> StagedColumns:
@@ -482,7 +507,7 @@ def repartition_staged(mesh: Mesh, staged: StagedColumns) -> StagedColumns:
     from pixie_tpu.distributed import mesh as mesh_lib
 
     names = [f"blocks/{n}" for n in staged.blocks] + ["mask"]
-    if staged.gids is not None:
+    if staged.gids is not None or staged.branch_gids:
         names.append("gids")
     sh = mesh_lib.match_partition_rules(
         mesh_lib.STAGED_PARTITION_RULES, names, mesh
@@ -499,6 +524,10 @@ def repartition_staged(mesh: Mesh, staged: StagedColumns) -> StagedColumns:
             if staged.gids is not None
             else None
         ),
+        branch_gids={
+            k: jax.device_put(g, sh["gids"])
+            for k, g in staged.branch_gids.items()
+        },
     )
 
 

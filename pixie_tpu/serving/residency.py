@@ -71,7 +71,7 @@ LOW_WATERMARK = 0.80
 
 def staged_nbytes(staged: Any) -> int:
     """Device bytes of a StagedColumns entry: column blocks + validity
-    mask + (optional) gid blocks. jax arrays report their on-device
+    mask + (optional) gid blocks, per branch for a fan-out. jax arrays report their on-device
     nbytes; anything without the attribute (test shims) counts 0."""
     total = 0
     for a in getattr(staged, "blocks", {}).values():
@@ -82,6 +82,8 @@ def staged_nbytes(staged: Any) -> int:
     gids = getattr(staged, "gids", None)
     if gids is not None:
         total += int(getattr(gids, "nbytes", 0))
+    for g in getattr(staged, "branch_gids", {}).values():
+        total += int(getattr(g, "nbytes", 0))
     return total
 
 

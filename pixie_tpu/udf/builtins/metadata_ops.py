@@ -178,7 +178,9 @@ def register(r: Registry) -> None:
         "nslookup",
         (S,),
         S,
-        lambda st, ip: st.dns.get(ip, ip),
+        # With no metadata state, every address is unresolved: it comes
+        # back as itself, as an address the state cannot resolve does.
+        lambda st, ip: ip if st is None else st.dns.get(ip, ip),
     )
     reg("_exec_hostname", (), S, lambda st: st.hostname)
 
