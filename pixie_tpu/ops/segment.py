@@ -23,9 +23,11 @@ import jax.numpy as jnp
 # segments, with cost scaling ~n*num_segments beyond. Non-sum reductions
 # (max/min) have no einsum form; above SORTED_MIN_ROWS they ride the r8
 # sort–COMPACT lane instead (two i32-class sorts + an O(num_segments)
-# scatter; see sorted_segment_reduce_compact). CPU prefers scatter
-# everywhere. Tests can pin a strategy via set_strategy() /
+# scatter; see sorted_segment_reduce_compact); int64/float64 sums past
+# the MXU's segment range sort too (sorted_segment_sum). CPU prefers
+# scatter everywhere. Tests can pin a strategy via set_strategy() /
 # set_sorted_strategy().
+import contextlib
 import threading
 
 from pixie_tpu.utils import flags
@@ -147,6 +149,21 @@ LANE_COUNTS: dict[str, int] = {}
 
 def lane_count(name: str) -> None:
     LANE_COUNTS[name] = LANE_COUNTS.get(name, 0) + 1
+    for sink in getattr(_TLS, "sinks", ()):
+        sink.add(name)
+
+
+@contextlib.contextmanager
+def lane_sink(lanes: set):
+    """Add to the set ``lanes`` every lane counted on this thread inside
+    the with-block: a program that opens one around its traced body keeps
+    the lanes it was compiled with."""
+    stack = _TLS.__dict__.setdefault("sinks", [])
+    stack.append(lanes)
+    try:
+        yield
+    finally:
+        stack.pop()
 
 
 def reduce_lanes(reset: bool = False) -> dict:
@@ -449,6 +466,99 @@ def sorted_segment_minmax_compact(
     )
 
 
+def _run_sums(values, run_start):
+    """Inclusive sums of ``values`` that restart at every ``run_start``:
+    a Hillis–Steele scan, ceil(log2 n) steps of one contiguous shift and
+    one add over the whole array. (lax.associative_scan's strided halves
+    took minutes to compile for the TPU at 2^21 rows.)"""
+    n = values.shape[0]
+    acc, started = values, run_start
+    step = 1
+    while step < n:
+        prev = jnp.concatenate([jnp.zeros(step, acc.dtype), acc[:-step]])
+        prev_started = jnp.concatenate(
+            [jnp.zeros(step, jnp.bool_), started[:-step]]
+        )
+        acc = jnp.where(started, acc, prev + acc)
+        started = started | prev_started
+        step *= 2
+    return acc
+
+
+# Rows one sort of sorted_segment_sum spans. A TPU sort that carries
+# 64-bit values is slow to compile, and a fold program holds one per
+# 64-bit sum: in 2^17-row tiles the http_node fold (2^21-row blocks,
+# 32,768 segments, an int64 and a float64 sum) compiles in 47 s for a
+# described v5e, untiled in 77 s, with both sums scattered in 13 s.
+# Tiles cost run time (each tile's segment table): on a v5e, that
+# block's two sums take 40-43 ms tiled and 19.5 ms untiled, against
+# the scatters' 296-436 ms.
+_SUM_SORT_ROWS = 1 << 17
+
+
+def sorted_segment_sum(values, seg_ids, num_segments: int, mask=None):
+    """Per-segment sums of int64 or float64 values with no 64-bit
+    scatter of the values. Each tile of rows sorts (segment id, value)
+    on the id alone; a scan over the sorted values that restarts at every
+    run (and tile) is read at each run's last row, found from the tile's
+    segment counts; the tiles' run sums add up per segment.
+
+    int64: every add wraps modulo 2^64 as the scatter's do, so the sums
+    are bit-identical. float64: each segment's rounding is its own (a
+    difference of global prefix sums would cancel against every row
+    sorted before it); the sort moves the value and never compares or
+    rounds it (the TPU cannot bitcast an emulated f64 to int64 bits).
+    Masked rows and ids outside [0, num_segments) count for no segment;
+    empty segments read 0.
+
+    Cost, for n rows in t = ceil(n / 2^17) tiles: the tiles' sorts
+    (n log n), a scan of ceil(log2 n) steps over n, and a segment table
+    a tile, t x (num_segments + 1) wide: one int32 scatter of n counts
+    into it, its cumsum, a 64-bit gather of the run ends and the sum
+    over tiles. So the table outgrows the rows once num_segments passes
+    n / t; sum_sorted_strategy keeps the lane below that."""
+    n = values.shape[0]
+    if n == 0:
+        return jnp.zeros(num_segments, values.dtype)
+    seg = seg_ids.astype(jnp.int32)
+    keep = (seg >= 0) & (seg < num_segments)
+    if mask is not None:
+        keep = keep & mask
+    seg = jnp.where(keep, seg, jnp.int32(num_segments))
+    tiles = -(-n // _SUM_SORT_ROWS)
+    rows = -(-n // tiles)
+    pad = tiles * rows - n
+    if pad:
+        seg = jnp.concatenate([seg, jnp.full(pad, num_segments, jnp.int32)])
+        values = jnp.concatenate([values, jnp.zeros(pad, values.dtype)])
+    seg = seg.reshape(tiles, rows)
+    seg_s, val_s = jax.lax.sort(
+        (seg, values.reshape(tiles, rows)), dimension=1, num_keys=1
+    )
+    run_start = jnp.concatenate(
+        [jnp.ones((tiles, 1), jnp.bool_), seg_s[:, 1:] != seg_s[:, :-1]],
+        axis=1,
+    )
+    run_sum = _run_sums(val_s.reshape(-1), run_start.reshape(-1))
+    # Each tile's run bounds from its segment counts (an int32 scatter,
+    # which every sum of the block shares): the run of segment s ends at
+    # the tile's count of ids <= s.
+    width = num_segments + 1
+    tile_key = jnp.arange(tiles, dtype=jnp.int32)[:, None] * width + seg
+    counts = jax.ops.segment_sum(
+        jnp.ones(tiles * rows, jnp.int32),
+        tile_key.reshape(-1),
+        num_segments=tiles * width,
+    ).reshape(tiles, width)[:, :num_segments]
+    end = jnp.cumsum(counts, axis=1)
+    last = jnp.take_along_axis(
+        run_sum.reshape(tiles, rows), jnp.maximum(end - 1, 0), axis=1
+    )
+    return jnp.where(counts > 0, last, jnp.zeros((), values.dtype)).sum(
+        axis=0
+    )
+
+
 def sorted_segment_counts(flat, nseg: int, mask=None):
     """Per-segment counts via sort + run-length + compaction (r8: the
     r4 unique-index scatter was still FULL-length — XLA walks every
@@ -590,6 +700,29 @@ def compact_unmatched_rows(unmatched, cap: int):
     return out
 
 
+def sum_sorted_strategy(n_rows: int, num_segments: int, dtype) -> bool:
+    """Should seg_sum take sorted_segment_sum? Only 64-bit sums above
+    MATMUL_MAX_SEGMENTS (below it they ride the MXU), and then — unless
+    set_sorted_strategy forces it — on a TPU-class platform, with the
+    ``sorted_compact`` flag on, at least SORTED_MIN_ROWS rows, and
+    segment tables (one a 2^17-row tile) no larger than the rows. Reads
+    shapes, dtype and platform only: no cost model, so one process
+    compiles the same program as the next."""
+    if jnp.dtype(dtype) not in (jnp.dtype(jnp.int64), jnp.dtype(jnp.float64)):
+        return False
+    if num_segments <= MATMUL_MAX_SEGMENTS:
+        return False
+    if _FORCE_SORTED is not None:
+        return _FORCE_SORTED
+    if not flags.sorted_compact or n_rows < SORTED_MIN_ROWS:
+        return False
+    tiles = -(-n_rows // _SUM_SORT_ROWS)
+    if tiles * (num_segments + 1) > n_rows:
+        return False
+    platform = getattr(_TLS, "hint", None) or jax.default_backend()
+    return platform != "cpu"
+
+
 def seg_sum(values, seg_ids, num_segments: int, mask=None):
     if _use_matmul(num_segments) and jnp.issubdtype(
         values.dtype, jnp.floating
@@ -609,6 +742,9 @@ def seg_sum(values, seg_ids, num_segments: int, mask=None):
             limb_rows_i64(v), seg_ids.astype(jnp.int32), num_segments
         )
         return reconstruct_i64(totals)
+    if sum_sorted_strategy(values.shape[0], num_segments, values.dtype):
+        lane_count("sum_sorted")
+        return sorted_segment_sum(values, seg_ids, num_segments, mask)
     v = values if mask is None else jnp.where(mask, values, 0)
     return jax.ops.segment_sum(v, seg_ids, num_segments=num_segments)
 

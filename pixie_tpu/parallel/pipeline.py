@@ -1120,6 +1120,10 @@ class MeshExecutor:
         # traced+compiled shard_map (aux LUTs/constants are ARGUMENTS, so
         # dictionary growth does not invalidate the executable).
         self._program_cache: dict[str, Any] = {}
+        # Fold signature -> the reduction lanes (segment.LANE_COUNTS
+        # names) its program was traced with; the device.program span
+        # reports them.
+        self._program_lanes: dict[str, set] = {}
         # HBM-resident staged-table cache — the device-side cold tier: a
         # table version is staged once and every matching query hits HBM
         # directly (the reference's analogue is the compacted Arrow cold
@@ -2237,8 +2241,10 @@ class MeshExecutor:
                     aux = self._build_aux(
                         evaluator, m, key_plan, table, device_specs
                     )
-                with _timed("program"):
-                    merged, capacity = self._fold(b, staged, aux, cache_key)
+                with _timed("program") as sp:
+                    merged, capacity = self._fold(
+                        b, staged, aux, cache_key, sp
+                    )
             if (
                 self.fold_signature_store is not None
                 and staged is not None
@@ -2362,7 +2368,7 @@ class MeshExecutor:
                 with _timed("program") as sp:
                     sp.set(agg=nid)
                     merged, capacity = self._fold(
-                        b, view, aux, (cache_key, nid)
+                        b, view, aux, (cache_key, nid), sp
                     )
                 with _timed("finalize") as sp:
                     sp.set(agg=nid)
@@ -2430,19 +2436,29 @@ class MeshExecutor:
         with _timed("stage"):
             return stage()
 
-    def _fold(self, b, staged, aux, cache_key):
-        """(merged, capacity) of branch ``b``'s fold over ``staged``."""
+    def _fold(self, b, staged, aux, cache_key, span):
+        """(merged, capacity) of branch ``b``'s fold over ``staged``;
+        ``span`` (the ``device.program`` span) gains ``lanes``, the
+        reduction lanes of the fold program that ran."""
         if flags.shared_scans:
             # Shared scan (r12): coalesce with any concurrent query whose
             # fold signature + aux values match — one device dispatch,
             # per-query finalize.
             return self._shared_scan_run(
                 b.m, b.device_specs, b.evaluator, b.key_plan, staged, aux,
-                cache_key,
+                cache_key, span,
             )
         return self._run_program(
-            b.m, b.device_specs, b.evaluator, b.key_plan, staged, aux
+            b.m, b.device_specs, b.evaluator, b.key_plan, staged, aux, span
         )
+
+    def _note_lanes(self, span, fold_sig):
+        """Set ``lanes`` on ``span``: the reduction lanes (segment
+        LANE_COUNTS names) the fold program ``fold_sig`` was traced
+        with."""
+        lanes = self._program_lanes.get(fold_sig)
+        if span is not None and lanes:
+            span.set(lanes=",".join(sorted(lanes)))
 
     def _finalize_branch(self, b, merged, capacity, registry, table):
         """Branch ``b``'s output: raw states (PARTIAL), one RowBatch per
@@ -5237,6 +5253,7 @@ class MeshExecutor:
             lambda: self._build_fold(
                 specs, evaluator, key_plan, col_names, narrow_names,
                 int_dict_names, aux_key_order, capacity, n_leaves, treedef,
+                self._program_lanes.setdefault(fold_sig, set()),
             ),
             n_aux=len(aux_vals),
         )
@@ -6133,13 +6150,17 @@ class MeshExecutor:
         capacity,
         n_state_leaves,
         treedef,
+        lanes,
     ):
         """The FOLD unit: scan a set of blocks (one stream window, or the
         whole staged table on the warm path), return the updated
         per-device states. No collectives — those live in the merge unit,
         so every fold dispatch is device-local and async, and the fold
         executable is reused by any query whose scan lane matches
-        (_fold_signature), regardless of finalize."""
+        (_fold_signature), regardless of finalize. Tracing adds the
+        reduction lanes it takes to the set ``lanes``."""
+        from pixie_tpu.ops import segment as _segment
+
         axis = self.mesh_axes  # collectives reduce over the FULL mesh
         has_host_gids = key_plan.host_gids is not None
         has_key_lut = isinstance(key_plan.device_expr, tuple)
@@ -6184,7 +6205,8 @@ class MeshExecutor:
                 mask_all,
                 gids_all if gids_all is not None else mask_all,
             )
-            carry, _ = jax.lax.scan(body, carry, xs)
+            with _segment.lane_sink(lanes):
+                carry, _ = jax.lax.scan(body, carry, xs)
             return tuple(leaf[None] for leaf in jax.tree.leaves(carry))
 
         n_sharded = (
@@ -6800,7 +6822,8 @@ class MeshExecutor:
         return values, presence
 
     def _shared_scan_run(
-        self, m, specs, evaluator, key_plan, staged, aux, cache_key
+        self, m, specs, evaluator, key_plan, staged, aux, cache_key,
+        span=None,
     ):
         """Run the fold through the shared-scan coordinator (r12, flag
         ``shared_scans``): concurrent queries whose coalescing key
@@ -6891,7 +6914,7 @@ class MeshExecutor:
                     m, specs, evaluator, key_plan, staged, shared_aux,
                     terms,
                 )
-        return self._shared_scans.run(
+        out = self._shared_scans.run(
             key,
             lambda: self._run_program(
                 m, specs, evaluator, key_plan, staged, aux
@@ -6900,6 +6923,8 @@ class MeshExecutor:
             terms=terms,
             compute_batch=compute_batch,
         )
+        self._note_lanes(span, "fold|" + fold_sig)
+        return out
 
     # -- predicate-batched shared scans (r16) --------------------------------
     # Crescando/SharedDB posture: concurrent queries whose fold shapes
@@ -7396,7 +7421,9 @@ class MeshExecutor:
                 "fold-shape record failed (ignored)", exc_info=True
             )
 
-    def _run_program(self, m, specs, evaluator, key_plan, staged, aux):
+    def _run_program(
+        self, m, specs, evaluator, key_plan, staged, aux, span=None
+    ):
         """Execute the staged aggregation. Default (program_decompose):
         separately-cached init/fold/merge/finalize units — a query that
         differs only in finalize (output names, FULL vs PARTIAL, a new
@@ -7529,6 +7556,7 @@ class MeshExecutor:
                 per_pass.append(
                     self._unpack_outputs(templates, capacity, buf)
                 )
+        self._note_lanes(span, fold_sig)
         return self._recombine_passes(per_pass, specs, capacity, n_passes)
 
     def _run_program_fused(

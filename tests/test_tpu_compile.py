@@ -123,3 +123,30 @@ def test_sketch_update(one_chip, sketch):
         ((n,), value_dtype),
         ((n,), jnp.bool_),
     )
+
+
+def test_sum_sorted_lane(one_chip):
+    """The f64 error sum of one http_node.history fold block: 2^21 rows
+    into 32,768 segments, above the MXU lane, takes the sorted lane: one
+    sort, in 2^17-row tiles, that carries the emulated f64 values (the
+    chip cannot bitcast them), and no scatter but the tiles' int32
+    segment counts."""
+    n, nseg = 1 << 21, 32768
+
+    def fn(failures, gids, mask):
+        return segment.seg_sum(failures, gids, nseg, mask)
+
+    compiled, lanes = compile_tpu(
+        fn,
+        one_chip,
+        ((n,), jnp.float64),
+        ((n,), jnp.int32),
+        ((n,), jnp.bool_),
+    )
+    assert lanes.get("sum_sorted") == 1, lanes
+    hlo = compiled.as_text().splitlines()
+    sorts = [ln for ln in hlo if " sort(" in ln]
+    assert len(sorts) == 1 and "[16,131072]" in sorts[0], sorts
+    scatters = [ln for ln in hlo if " scatter(" in ln]
+    assert scatters and all("= s32[" in ln for ln in scatters), scatters
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
