@@ -135,13 +135,6 @@ width is the correctness gate, the always-present ``mesh_scaling_x``
 headline (per-device fold rate at width 4 vs 1-host) must stay >= 0.7,
 and the sweep lands in BENCH_DETAIL.json's ``mesh`` key.
 
-Cost model (r22): config 11 (opt-in, BENCH_CONFIGS=...,11) measures the
-learned CostModel's prediction accuracy over real engine dispatches
-through tools/microbench_cost_model.py: the warmed pooled p50 relative
-error (predict-before-ingest vs measured wall) must stay <= 0.30, the
-headline ``cost_model_warmed_p50_accuracy_x`` is its inverse, and the
-sweep lands in BENCH_DETAIL.json's ``cost_model`` key.
-
 Mesh chaos recovery (r23): config 12 (opt-in, BENCH_CONFIGS=...,12)
 kills one simulated host mid-stream during a windowed fold at
 hosts:2,d:N/2 (tools/microbench_mesh.py MB_MESH_CHAOS path): the
@@ -168,7 +161,7 @@ cache, BENCH_SOAK_CLIENTS/BENCH_SOAK_REQUESTS/BENCH_SOAK_ROWS for
 config 6, BENCH_FLEET_AGENTS/BENCH_FLEET_CLIENTS/BENCH_FLEET_ROWS/
 BENCH_FLEET_TABLES/BENCH_FLEET_HBM_MB for config 7, BENCH_JOIN_ROWS
 for config 8, BENCH_VIEWS_CLIENTS/BENCH_VIEWS_REQUESTS/
-BENCH_VIEWS_ROWS for config 9, BENCH_CM_ROWS for config 11,
+BENCH_VIEWS_ROWS for config 9,
 BENCH_MESH_ROWS/BENCH_MESH_WINDOWS for config 12,
 BENCH_INGEST_SECONDS/BENCH_INGEST_FEEDERS/BENCH_INGEST_CLIENTS for
 config 13.
@@ -653,8 +646,8 @@ def main() -> None:
         if c.strip()
     ]
     unknown = set(order) - {
-        "0", "1", "2", "3", "4", "5", "6", "7", "8", "9", "10", "11",
-        "12", "13",
+        "0", "1", "2", "3", "4", "5", "6", "7", "8", "9", "10", "12",
+        "13",
     }
     if unknown:
         raise SystemExit(f"BENCH_CONFIGS has unknown entries: {unknown}")
@@ -1358,40 +1351,6 @@ def main() -> None:
         )
         microbench_mesh.record_mesh_detail(summary)
 
-    # ---- config 11: cost-model prediction accuracy (r22) ------------------
-    def run_config_11():
-        # Cold-vs-warmed relative prediction error of the r22 CostModel
-        # over real engine dispatches; the warmed pooled p50 must stay
-        # within 30% of measured wall time — the r22 acceptance bar.
-        # Opt-in via BENCH_CONFIGS=...,11.
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        import microbench_cost_model
-
-        summary = microbench_cost_model.run_cost_model_bench(
-            rows=int(os.environ.get("BENCH_CM_ROWS", 120_000)),
-            warm_runs=max(runs, 8),
-        )
-        assert summary["pass_p50_under_030"], summary
-        ledger.add(
-            {
-                "config": 11,
-                "cold_predictions": summary["cold"]["predictions"],
-                "warmed_predictions": summary["warmed"]["predictions"],
-                "warmed_p90_rel_err": summary["warmed_p90_rel_err"],
-                # Always-present headline, inverted so "higher is
-                # better" matches the ledger's regression gate: 1/p50
-                # falling below ~3.3 means the warmed model drifted
-                # past the 30% error bar.
-                "warmed_p50_rel_err": summary["warmed_p50_rel_err"],
-                "metric": "cost_model_warmed_p50_accuracy_x",
-                "value": round(
-                    1.0 / max(summary["warmed_p50_rel_err"], 1e-6), 3
-                ),
-                "unit": "inv_rel_err",
-            }
-        )
-        microbench_cost_model.record_cost_model_detail(summary)
-
     # ---- config 12: mesh chaos recovery (r23) -----------------------------
     def run_config_12():
         # One simulated host killed mid-stream: the degraded-geometry
@@ -1491,7 +1450,6 @@ def main() -> None:
         "8": run_config_8,
         "9": run_config_9,
         "10": run_config_10,
-        "11": run_config_11,
         "12": run_config_12,
         "13": run_config_13,
     }

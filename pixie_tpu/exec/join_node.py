@@ -15,7 +15,6 @@ the reference's specified build side).
 
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 import numpy as np
@@ -28,20 +27,6 @@ from pixie_tpu.table.row_batch import RowBatch
 from pixie_tpu.types import Relation
 
 OUTPUT_CHUNK_ROWS = 1 << 17
-
-# r22: lazy handle on the serving-layer cost model (importing it at
-# module level would cycle through serving → vizier → parallel → exec).
-_COST_MODEL = None
-
-
-def _cost_model():
-    global _COST_MODEL
-    if _COST_MODEL is None:
-        from pixie_tpu.serving import cost_model
-
-        _COST_MODEL = cost_model
-    return _COST_MODEL
-
 
 class EquijoinNode(ExecNode):
     # Matched probe rows are emitted before (possibly earlier-timed)
@@ -63,38 +48,16 @@ class EquijoinNode(ExecNode):
         self._probe_eos = False
         self._left_relation: Optional[Relation] = None
         self._right_relation: Optional[Relation] = None
-        # r22 cost model: host-lane wall/rows, observed once at eos as
-        # the ``join|host`` family the device-join gate compares against.
-        self._cost_wall_s = 0.0
-        self._cost_rows = 0
-        self._cost_observed = False
 
     def set_input_relations(self, left: Relation, right: Relation) -> None:
         self._left_relation = left
         self._right_relation = right
 
     def consume_next_impl(self, exec_state, batch, parent_index: int) -> None:
-        cm = _cost_model()
-        if not cm.ACTIVE:
-            if parent_index == 0:
-                self._consume_build(exec_state, batch)
-            else:
-                self._consume_probe(exec_state, batch)
-            return
-        t0 = time.perf_counter()
-        try:
-            if parent_index == 0:
-                self._consume_build(exec_state, batch)
-            else:
-                self._consume_probe(exec_state, batch)
-        finally:
-            self._cost_wall_s += time.perf_counter() - t0
-            self._cost_rows += int(batch.num_rows)
-            if self._sent_eos and not self._cost_observed:
-                self._cost_observed = True
-                cm.observe_family(
-                    "join|host", self._cost_rows, self._cost_wall_s
-                )
+        if parent_index == 0:
+            self._consume_build(exec_state, batch)
+        else:
+            self._consume_probe(exec_state, batch)
 
     # -- build --------------------------------------------------------------
     def _consume_build(self, exec_state, batch: RowBatch) -> None:

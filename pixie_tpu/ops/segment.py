@@ -32,21 +32,6 @@ import threading
 
 from pixie_tpu.utils import flags
 
-# r22 cost model, resolved lazily: serving's package init transitively
-# imports this module (controller -> vizier -> engine -> pipeline), so a
-# top-level import here would deadlock the import graph. sorted_strategy
-# runs at trace time only, so the one-time resolution cost is free.
-_COST_MODEL = None
-
-
-def _cost_model():
-    global _COST_MODEL
-    if _COST_MODEL is None:
-        from pixie_tpu.serving import cost_model
-
-        _COST_MODEL = cost_model
-    return _COST_MODEL
-
 _FORCE: Optional[str] = None
 _TLS = threading.local()  # per-thread platform hint: agents run in threads
 MATMUL_MAX_SEGMENTS = 8192
@@ -121,24 +106,12 @@ def sorted_strategy(n_rows: Optional[int] = None, nseg: Optional[int] = None) ->
         return _FORCE_SORTED
     if not flags.sorted_compact:
         return False
-    default = True
     if n_rows is not None and n_rows < SORTED_MIN_ROWS:
-        default = False
+        return False
     if n_rows is not None and nseg is not None and nseg * 4 > n_rows:
-        default = False
-    if default:
-        platform = getattr(_TLS, "hint", None) or jax.default_backend()
-        default = platform != "cpu"
-    # r22: with measured wall times for BOTH lane families at this row
-    # bucket, the cost model may overrule the platform/row heuristic —
-    # within rails (never sorted far below SORTED_MIN_ROWS, never when
-    # nseg*4 > n_rows). Cold or disabled, `default` passes through
-    # untouched. Trace-time only: this never runs inside a compiled
-    # program.
-    cm = _cost_model()
-    if cm.ACTIVE and n_rows is not None:
-        return cm.choose_sorted_lane(n_rows, nseg, default, SORTED_MIN_ROWS)
-    return default
+        return False
+    platform = getattr(_TLS, "hint", None) or jax.default_backend()
+    return platform != "cpu"
 
 
 # -- reduction-lane telemetry: which lane each traced program chose.
@@ -706,8 +679,8 @@ def sum_sorted_strategy(n_rows: int, num_segments: int, dtype) -> bool:
     set_sorted_strategy forces it — on a TPU-class platform, with the
     ``sorted_compact`` flag on, at least SORTED_MIN_ROWS rows, and
     segment tables (one a 2^17-row tile) no larger than the rows. Reads
-    shapes, dtype and platform only: no cost model, so one process
-    compiles the same program as the next."""
+    shapes, dtype and platform only, so one process compiles the same
+    program as the next."""
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.int64), jnp.dtype(jnp.float64)):
         return False
     if num_segments <= MATMUL_MAX_SEGMENTS:

@@ -75,22 +75,6 @@ from pixie_tpu.parallel import profiler as resattr
 from pixie_tpu.distributed import mesh as mesh_lib
 from pixie_tpu.utils import faults, flags, metrics_registry, trace
 
-# r22 learned cost model, resolved lazily (serving's package init
-# transitively imports this module, so a top-level import would cycle).
-# After first resolution every gate is `_cost_model().ACTIVE` — a cached
-# global + attribute load, held <1% by microbench_fault_overhead's
-# cost_model_overhead key.
-_COST_MODEL = None
-
-
-def _cost_model():
-    global _COST_MODEL
-    if _COST_MODEL is None:
-        from pixie_tpu.serving import cost_model
-
-        _COST_MODEL = cost_model
-    return _COST_MODEL
-
 _M = metrics_registry()
 _OFFLOAD_HITS = _M.counter(
     "device_offload_total", "Fragments executed on the device mesh."
@@ -1227,18 +1211,13 @@ class MeshExecutor:
         # Window accounting of the most recent checkpoint resume
         # (bench config 12 reads the refolded-window fraction here).
         self.last_resume_stats: "dict | None" = None
-        # Fold signatures that completed at least one multi-axis
-        # dispatch on this executor: the DERIVED watchdog deadline only
-        # arms for these — a first dispatch may compile inline (AOT
-        # miss / monolithic fallback), and a cost-model prediction of
-        # steady-state fold wall says nothing about compile time.
-        self._warm_dispatch_sigs: set = set()
-        # Worst multi-axis dispatch wall observed on this executor
-        # (abandoned dispatches report theirs too): the derived
-        # watchdog deadline rails over this as well as the model's
-        # solo prediction, so a loaded process does not read its own
-        # ambient slowness as a hang.
+        # Worst completed multi-axis dispatch wall on this executor,
+        # overall and per fold signature (abandoned dispatches report
+        # theirs too when they finish): the derived watchdog deadline
+        # is built from these, and arms only for a signature that has
+        # completed a dispatch here.
         self._dispatch_wall_max = 0.0
+        self._sig_wall_max: dict[str, float] = {}
 
     # -- public -------------------------------------------------------------
     @staticmethod
@@ -1713,40 +1692,27 @@ class MeshExecutor:
             raise last_err
         return None
 
-    def _watchdog_deadline(self, fold_sig=None, warm=True) -> "float | None":
+    def _watchdog_deadline(self, fold_sig=None) -> "float | None":
         """Collective-watchdog deadline for one sharded dispatch, or
-        None (no watchdog). The flag wins when positive; 0 derives the
-        deadline from the r22 CostModel prediction x the rail factor
-        (no opinion = no watchdog — a deadline must come from evidence);
-        negative disables outright. A derived deadline additionally
-        requires ``warm`` — this signature already completed a dispatch
-        here — because a cold dispatch may compile inline and the model
-        predicts steady-state fold wall, not XLA compile time."""
+        None (no watchdog). The flag wins when positive; negative
+        disables the watchdog. At 0 the deadline comes from this
+        executor's own completed walls, and only once ``fold_sig`` has
+        completed a dispatch here: a first dispatch may compile inline,
+        and no steady-state wall says how long that takes. The 0.25 s
+        floor keeps a microsecond-scale fold from tripping on scheduler
+        jitter, and 4x the slowest wall of any signature keeps ambient
+        load (clients, agents, a second executor on the same cores) from
+        reading as a hang: the watchdog hunts hangs, which are
+        unbounded."""
         t = float(flags.mesh_dispatch_timeout_s)
         if t > 0:
             return t
-        if t < 0 or not warm:
+        sig_wall = self._sig_wall_max.get(fold_sig)
+        if t < 0 or sig_wall is None:
             return None
-        cm = _cost_model()
-        if not cm.ACTIVE:
-            return None
-        pred = (
-            cm.predict_seconds(sig=fold_sig)
-            if fold_sig is not None
-            else cm.predict_seconds(family="fold")
-        )
-        if not pred or pred <= 0:
-            return None
-        # Floor keeps a microsecond-scale prediction from tripping on
-        # ordinary scheduler jitter, and the worst dispatch wall seen
-        # locally x4 keeps ambient load from masquerading as a hang:
-        # the model predicts SOLO wall, but this process may be running
-        # clients, agents, and a second executor on the same cores. The
-        # watchdog hunts HANGS — a hang is unbounded, 4x the slowest
-        # completed dispatch is not.
         return max(
             0.25,
-            pred * float(flags.mesh_watchdog_rail_factor),
+            sig_wall * float(flags.mesh_watchdog_rail_factor),
             self._dispatch_wall_max * 4.0,
         )
 
@@ -1777,24 +1743,26 @@ class MeshExecutor:
                     raise mesh_lib.MeshGeometryError(
                         "collective_timeout", f"{what} on {self._mesh_sig}"
                     )
-            deadline = self._watchdog_deadline(
-                fold_sig, warm=fold_sig in self._warm_dispatch_sigs
-            )
+
+            def timed():
+                # Dispatch is ASYNC even on CPU: fn() returns once the
+                # program is enqueued. Block before releasing the lock
+                # or the next all-device program overlaps this one's
+                # still-running collectives and wedges the rendezvous.
+                # On the watchdog's reaper thread the wall is recorded
+                # even when the caller already gave up on this dispatch:
+                # a false trip (slow-but-healthy collective) raises the
+                # walls, so the NEXT deadline clears it.
+                t0 = time.perf_counter()
+                out = jax.block_until_ready(fn())
+                self._note_dispatch_wall(time.perf_counter() - t0, fold_sig)
+                return out
+
+            deadline = self._watchdog_deadline(fold_sig)
             if deadline is not None:
-                out = self._watchdog_run(deadline, fn, what)
-            else:
-                with _MESH_COLLECTIVE_LOCK:
-                    t0 = time.perf_counter()
-                    # Dispatch is ASYNC even on CPU: fn() returns once
-                    # the program is enqueued. Block before releasing
-                    # the lock or the next all-device program overlaps
-                    # this one's still-running collectives and wedges
-                    # the rendezvous.
-                    out = jax.block_until_ready(fn())
-                    self._note_dispatch_wall(time.perf_counter() - t0)
-            if fold_sig is not None:
-                self._warm_dispatch_sigs.add(fold_sig)
-            return out
+                return self._watchdog_run(deadline, timed, what)
+            with _MESH_COLLECTIVE_LOCK:
+                return timed()
         if len(self._full_mesh_config.axes) > 1:
             # Degraded-rung dispatch of a multi-axis executor: the flat
             # program still rendezvouses every device, so it must not
@@ -1805,9 +1773,14 @@ class MeshExecutor:
                 return jax.block_until_ready(fn())
         return fn()
 
-    def _note_dispatch_wall(self, wall: float) -> None:
+    def _note_dispatch_wall(self, wall: float, fold_sig=None) -> None:
+        # Called under _MESH_COLLECTIVE_LOCK (see _mesh_dispatch).
         if wall > self._dispatch_wall_max:
             self._dispatch_wall_max = wall
+        if fold_sig is not None and wall > self._sig_wall_max.get(
+            fold_sig, 0.0
+        ):
+            self._sig_wall_max[fold_sig] = wall
 
     def _watchdog_run(self, deadline: float, fn, what: str):
         from pixie_tpu.ops import segment as _segment
@@ -1825,7 +1798,6 @@ class MeshExecutor:
             # pool, which is strictly worse than queueing behind it.
             with _MESH_COLLECTIVE_LOCK:
                 started.set()
-                t0 = time.perf_counter()
                 try:
                     # First call may trace: carry the caller's platform
                     # hint onto the reaper thread so lane strategy
@@ -1837,19 +1809,13 @@ class MeshExecutor:
                 except BaseException as e:  # re-raised on the caller
                     box["error"] = e
                 finally:
-                    # Recorded even when the caller already gave up on
-                    # this dispatch: a false trip (slow-but-healthy
-                    # collective) raises the observed rail, so the NEXT
-                    # deadline clears it — one bad prediction cannot
-                    # cascade.
-                    self._note_dispatch_wall(time.perf_counter() - t0)
                     done.set()
 
         th = threading.Thread(target=run, name="mesh-watchdog", daemon=True)
         th.start()
         # Queue wait is NOT a hang: the deadline times the exclusive
         # execution window only — concurrent dispatches line up on the
-        # collective lock, and a cost-model prediction knows nothing
+        # collective lock, and a deadline from past walls knows nothing
         # about the queue in front of this one.
         started.wait()
         if not done.wait(timeout=deadline):
@@ -1983,12 +1949,6 @@ class MeshExecutor:
                     resattr.record_dispatch(
                         "fold", elapsed_ns / 1e9, program=bkey[:120]
                     )
-                cm = _cost_model()
-                if cm.ACTIVE:
-                    # r22: the whole-offload wall feeds the shapeless
-                    # ``fold`` cost family — the controller's predictive
-                    # term and admission's fold-seconds advisory.
-                    cm.observe_family("fold", 0, elapsed_ns / 1e9)
             return out
         except Exception as e:
             import logging
@@ -3270,21 +3230,8 @@ class MeshExecutor:
             nr = int(np.count_nonzero(right_sel))
         if nl == 0 or nr == 0:
             return None  # trivial side: the host hash join wins outright
-        cm = _cost_model()
-        if cm.ACTIVE:
-            # r22: with measured wall times for BOTH join lanes (device
-            # sort-merge vs host EquijoinNode — bit-identical outputs by
-            # the r19 contract) the cost model may move the
-            # device_join_min_rows gate, within rails: never device
-            # below flag/rail_factor rows. Cold or shadow, the default
-            # reproduces the flag comparison exactly.
-            if not cm.choose_device_join(
-                nl + nr, nl + nr >= int(flags.device_join_min_rows)
-            ):
-                return None
-        elif nl + nr < flags.device_join_min_rows:
+        if nl + nr < flags.device_join_min_rows:
             return None
-        _join_t0 = time.perf_counter()
         # Shared join-key id space over BOTH sides (the join-agg idiom):
         # string keys align through one StringDictionary, then a
         # GroupEncoder densifies; right-only keys get ids the left never
@@ -3389,12 +3336,6 @@ class MeshExecutor:
                 left_sel, right_sel, nl, nr,
             )
             if out is not None:
-                if cm.ACTIVE:
-                    cm.observe_family(
-                        "join|joinlane:sort_merge",
-                        nl + nr,
-                        time.perf_counter() - _join_t0,
-                    )
                 return m.join_nid, out
         ck_l = (
             m.left_source_op.table_name,
@@ -3440,15 +3381,6 @@ class MeshExecutor:
         )
         if out is None:
             return None
-        if cm.ACTIVE:
-            # r22: the device lane's measured wall (encode + stage +
-            # sort-merge dispatch) is the B side of the gate the cost
-            # model now decides.
-            cm.observe_family(
-                "join|joinlane:sort_merge",
-                nl + nr,
-                time.perf_counter() - _join_t0,
-            )
         return m.join_nid, out
 
     def _host_pred_mask(
@@ -6564,13 +6496,6 @@ class MeshExecutor:
                     "stream_fold", dt,
                     program=resattr.program_name(fold_sig),
                 )
-            cm = _cost_model()
-            if cm.ACTIVE:
-                # r22: padded window geometry is the shape that prices a
-                # stream fold (masked rows still flow through the lanes).
-                cm.observe(
-                    fold_sig, plan.d * plan.nblk * plan.b, dt
-                )
             # Double-buffer backpressure: block on window k-2's fold so
             # at most two windows are in flight (one transferring, one
             # packing) — bounds host-pinned buffers and the device
@@ -6676,17 +6601,6 @@ class MeshExecutor:
                             program=resattr.program_name(fold_sig),
                             rows=rows, staged_bytes=wbytes,
                             wire_bytes=nbytes,
-                        )
-                    cm = _cost_model()
-                    if cm.ACTIVE and w not in hits and wbytes > 0:
-                        # r22: staged-bytes/s per wire lane (codec vs
-                        # raw) calibrates the codec_min_ratio decision;
-                        # resident-ring hits moved ~nothing over the
-                        # wire and would pollute either rate.
-                        cm.observe_family(
-                            "stage|codec" if nbytes < wbytes
-                            else "stage|raw",
-                            int(wbytes), dt_put,
                         )
                     if cacheable:
                         win_blocks.append(dev_cols)
@@ -7382,9 +7296,6 @@ class MeshExecutor:
                         program=resattr.program_name(bsig),
                         rows=staged.num_rows,
                     )
-                cm = _cost_model()
-                if cm.ACTIVE:
-                    cm.observe(bsig, staged.num_rows, dt_b)
                 for s in range(nslots):
                     merged_flat = merge_p(*[leaf[:, s] for leaf in flat])
                     buf = fin_p(*merged_flat)

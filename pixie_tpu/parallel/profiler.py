@@ -217,16 +217,6 @@ def sample_pools() -> None:
             pass  # advisory; a sampling failure must never fail a flush
 
 
-def program_cost(sig: str) -> Optional[dict]:
-    """The registry row for a program signature (flops/bytes_accessed/
-    compile_seconds), or None when never recorded — the r22 cost
-    model's roofline prior reads cost_analysis through this instead of
-    reaching into the private registry."""
-    with _LOCK:
-        row = _PROGRAMS.get(sig)
-        return dict(row) if row is not None else None
-
-
 # -- drains (single consumer per process: the self-telemetry flush) ----------
 def drain_programs() -> list[dict]:
     with _LOCK:

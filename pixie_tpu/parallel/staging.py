@@ -27,26 +27,6 @@ from pixie_tpu.table.column import DictColumn
 from pixie_tpu.table.table import Table
 from pixie_tpu.utils import faults, flags, trace
 
-# r22: the codec-vs-raw bar consults the learned cost model (lazily —
-# serving's package init transitively imports the parallel package).
-_COST_MODEL = None
-
-
-def codec_min_ratio() -> float:
-    """Effective ``staging_codec_min_ratio`` for every codec plan site:
-    the flag, scaled by the cost model's measured codec-vs-raw staging
-    byte rates when warm (clamped to the flag's rail band), or the flag
-    exactly when the model is cold, shadowing, or disabled. Either lane
-    decodes bit-identically — this bar moves only wire bytes."""
-    global _COST_MODEL
-    if _COST_MODEL is None:
-        from pixie_tpu.serving import cost_model
-
-        _COST_MODEL = cost_model
-    if _COST_MODEL.ACTIVE:
-        return _COST_MODEL.codec_min_ratio()
-    return float(flags.staging_codec_min_ratio)
-
 DEFAULT_BLOCK_ROWS = 1 << 17
 
 # Cold-path phase timings (cumulative seconds since last reset): where a
@@ -397,7 +377,7 @@ def stage_columns(
             with timed("stage_encode", span=False):
                 cplan = _codec.plan_codec_local(
                     flat, d, nblk, b, num_rows,
-                    codec_min_ratio(),
+                    flags.staging_codec_min_ratio,
                 )
                 if cplan is not None:
                     try:
@@ -475,7 +455,7 @@ def stage_gids(
         with timed("stage_encode", span=False):
             gplan = _codec.plan_codec_local(
                 gflat, d, nblk, b, num_rows,
-                codec_min_ratio(),
+                flags.staging_codec_min_ratio,
             )
             if gplan is not None:
                 try:
@@ -804,7 +784,7 @@ def plan_stream(
             affine = kind in ("raw", "narrow") and bdt.kind in "iu"
             cp = _codec.plan_codec(
                 a, bdt, d, nblk, b, window_rows, num_rows,
-                codec_min_ratio(), affine,
+                flags.staging_codec_min_ratio, affine,
             )
             if cp is not None:
                 codecs[name] = cp
@@ -816,7 +796,7 @@ def plan_stream(
             # boundaries and diffs, so stats on the raw gids are exact.
             gid_codec = _codec.plan_codec(
                 gids, gid_dtype, d, nblk, b, window_rows, num_rows,
-                codec_min_ratio(), affine=True,
+                flags.staging_codec_min_ratio, affine=True,
             )
     return StreamPlan(
         col_plans=col_plans,

@@ -137,25 +137,16 @@ class _Ticket:
     """Held by an admitted query; release() frees the slot (idempotent).
     Usable as a context manager."""
 
-    def __init__(
-        self,
-        ctl: "AdmissionController",
-        tenant: str,
-        waited_s,
-        estimated_seconds: float = 0.0,
-    ):
+    def __init__(self, ctl: "AdmissionController", tenant: str, waited_s):
         self._ctl = ctl
         self.tenant = tenant
         self.waited_s = waited_s
-        # r22 advisory: the cost model's predicted fold seconds for this
-        # query at admission time (0 when the model was cold/off).
-        self.estimated_seconds = float(estimated_seconds)
         self._released = False
 
     def release(self) -> None:
         if not self._released:
             self._released = True
-            self._ctl._release(self.estimated_seconds)
+            self._ctl._release()
 
     def __enter__(self):
         return self
@@ -189,10 +180,6 @@ class AdmissionController:
         self._vclock = 0.0
         self._tenant_vtime: dict[str, float] = {}
         self._seq = itertools.count()
-        # r22: sum of the cost model's predicted fold seconds across
-        # admitted (unreleased) queries — a predicted-backlog signal for
-        # /statusz and the controller, never a rejection input.
-        self._inflight_seconds = 0.0
 
     # -- limits (flag-backed unless pinned at construction) ------------------
     def _limit(self) -> int:
@@ -226,10 +213,7 @@ class AdmissionController:
 
     # -- the front door ------------------------------------------------------
     def acquire(
-        self,
-        tenant: str = "default",
-        estimated_bytes: int = 0,
-        estimated_seconds: float = 0.0,
+        self, tenant: str = "default", estimated_bytes: int = 0
     ) -> _Ticket:
         """Block until admitted (WFQ order) or raise AdmissionRejected.
         Every exit path is bounded: queue-full and budget rejections are
@@ -241,13 +225,7 @@ class AdmissionController:
         budget check rejects a query whose staging could never fit
         even after evicting every unpinned entry — BEFORE the doomed
         cold stage starts, not once pinned bytes already exceed
-        budget.
-
-        ``estimated_seconds`` (r22): the cost model's predicted fold
-        seconds for this query — ADVISORY ONLY. It accumulates into the
-        predicted-inflight-seconds signal (``snapshot``) the controller
-        reads; it never rejects (bytes remain the only budget axis, so
-        disabling the model restores pre-r22 admission exactly)."""
+        budget."""
         t0 = time.monotonic()
         if not self._cv.acquire(blocking=False):
             w0 = time.perf_counter()
@@ -271,8 +249,7 @@ class AdmissionController:
                 self._publish()
                 _ADMITTED.inc(tenant=tenant)
                 _WAIT_SECONDS.observe(0.0, tenant=tenant)
-                self._inflight_seconds += max(float(estimated_seconds), 0.0)
-                return _Ticket(self, tenant, 0.0, estimated_seconds)
+                return _Ticket(self, tenant, 0.0)
             if self._waiting >= self._queue_cap():
                 self._reject(tenant, "queue_full", t0)
             w = _Waiter(
@@ -297,8 +274,7 @@ class AdmissionController:
             waited = time.monotonic() - t0
             _ADMITTED.inc(tenant=tenant)
             _WAIT_SECONDS.observe(waited, tenant=tenant)
-            self._inflight_seconds += max(float(estimated_seconds), 0.0)
-            return _Ticket(self, tenant, waited, estimated_seconds)
+            return _Ticket(self, tenant, waited)
         finally:
             self._cv.release()
 
@@ -358,13 +334,9 @@ class AdmissionController:
         window that slept or skipped one arrival too early)."""
         return self._waiting
 
-    def _release(self, estimated_seconds: float = 0.0) -> None:
+    def _release(self) -> None:
         with self._cv:
             self._active -= 1
-            self._inflight_seconds = max(
-                self._inflight_seconds - max(float(estimated_seconds), 0.0),
-                0.0,
-            )
             while self._heap and self._active < self._limit():
                 w = heapq.heappop(self._heap)
                 if w.abandoned:
@@ -404,9 +376,6 @@ class AdmissionController:
                 "lock_wait_p99_ms": round(
                     _LOCK_WAIT.quantile(0.99) * 1e3, 3
                 ),
-                # r22: predicted fold-seconds backlog across admitted
-                # queries (0 when the cost model is cold or off).
-                "predicted_inflight_s": round(self._inflight_seconds, 6),
             }
 
 
@@ -447,23 +416,6 @@ def estimate_staging_bytes(table, columns=None) -> int:
                 continue
             bpr += widths.get(c.data_type, 8)
     return int(rows * bpr)
-
-
-def estimate_fold_seconds(table) -> float:
-    """r22: the cost model's predicted fold seconds for a query over
-    ``table`` (row count / pooled fold-lane throughput). 0.0 when the
-    model is cold, shadowing, or off — the advisory simply disappears,
-    exactly the pre-r22 admission surface."""
-    from pixie_tpu.serving import cost_model
-
-    if not cost_model.ACTIVE or cost_model.SHADOW:
-        return 0.0
-    try:
-        rows = max(int(table.stats().num_rows), 0)
-        pred = cost_model.estimate_fold_seconds(rows)
-        return float(pred) if pred else 0.0
-    except Exception:
-        return 0.0
 
 
 def make_store_estimator(table_store):

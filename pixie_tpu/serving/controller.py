@@ -49,7 +49,6 @@ import threading
 import time
 from typing import Optional
 
-from pixie_tpu.serving import cost_model as _cost_model
 from pixie_tpu.utils import flags, metrics_registry
 from pixie_tpu.vizier.slo import CounterWindow, HistogramWindow
 
@@ -176,17 +175,6 @@ class AdmissionControlLoop:
                 depth = int(self._queue_depth_fn())
             except Exception:
                 depth = 0
-        # r22 predictive term: the cost model's expected time-in-queue
-        # for the CURRENT backlog at the CURRENT concurrency (learned
-        # per-fold median x depth / slots). 0.0 when the model is cold,
-        # shadowing, or off — the law below degrades to pure MIMD.
-        pred_wait = (
-            _cost_model.controller_predicted_wait_ms(
-                depth, max(int(flags.admission_max_concurrent), 1)
-            )
-            if _cost_model.ACTIVE
-            else None
-        )
         return {
             "admitted": admitted,
             "wait_p50_ms": (
@@ -201,7 +189,6 @@ class AdmissionControlLoop:
             "pinned_bytes": int(snap.get("pinned_bytes") or 0),
             "budget_bytes": int(snap.get("budget_bytes") or 0),
             "device_busy_s": self._device_busy_s(),
-            "predicted_wait_ms": float(pred_wait or 0.0),
         }
 
     # -- actuation -----------------------------------------------------------
@@ -276,17 +263,11 @@ class AdmissionControlLoop:
                 int(flags.admission_controller_holddown_windows), 0
             )
             return
-        reactive = sig["admitted"] > 0 and sig["wait_p50_ms"] > target_ms
-        # r22: actuate against PREDICTED fold cost — the model's
-        # expected queue-drain time for the live backlog — before the
-        # reactive windowed quantile has observed the slow folds. Same
-        # rails, same holddown, same brake; with the model cold/off
-        # predicted_wait_ms is 0 and this clause never fires.
-        predictive = (
-            sig.get("predicted_wait_ms", 0.0) > target_ms
-            and sig["queue_depth"] > 0
-        )
-        if (reactive or predictive) and self._hbm_headroom(sig):
+        if (
+            sig["admitted"] > 0
+            and sig["wait_p50_ms"] > target_ms
+            and self._hbm_headroom(sig)
+        ):
             self._idle_windows = 0
             if self._holddown > 0:
                 # Post-brake hold-down (r17): the wait signal still
@@ -310,8 +291,7 @@ class AdmissionControlLoop:
             self._actuate(
                 "admission_max_concurrent",
                 min(cur * 2, ceil),
-                "wait_p50_over_target" if reactive
-                else "predicted_wait_over_target",
+                "wait_p50_over_target",
                 sig,
             )
             return

@@ -49,7 +49,6 @@ import threading
 import time
 from typing import Any, Callable, Dict, FrozenSet, Iterable, List, Optional, Tuple
 
-from pixie_tpu.serving import cost_model as _cost_model
 from pixie_tpu.utils import flags, metrics_registry
 
 _M = metrics_registry()
@@ -250,16 +249,6 @@ class PlacementPlane:
             if any_eligible and not fits_somewhere:
                 return None, "mesh_fold"
         lat = agent_latency(fold_latency)
-        # r22: agents the latency view has never measured used to rank
-        # ``cold`` (below latency_fallback). With a warmed cost model
-        # the predicted per-fold latency stands in, so a known-cost
-        # workload ranks unmeasured agents on the latency rung — same
-        # answers (placement only routes), just better-ordered agents.
-        # Cold, shadow, or disabled: pred_ms is None and the ladder is
-        # exactly r18's.
-        pred_ms = None
-        if _cost_model.ACTIVE and not _cost_model.SHADOW:
-            pred_ms = _cost_model.placement_latency_ms()
         best: Optional[Tuple[Tuple, str, str]] = None
         with self._lock:
             aff = self._affinity.get(needed)
@@ -271,13 +260,12 @@ class PlacementPlane:
             aid = a["agent_id"]
             outcome = classify(coverage(a, needed))
             if outcome is None:
-                known = aid in lat or pred_ms is not None
-                outcome = "latency_fallback" if known else "cold"
+                outcome = "latency_fallback" if aid in lat else "cold"
             rank = (
                 _OUTCOME_ORDER[outcome],
                 0 if aid == aff else 1,
                 inflight.get(aid, 0) + load.get(aid, 0.0),
-                lat.get(aid, pred_ms if pred_ms is not None else 0.0),
+                lat.get(aid, 0.0),
                 aid,
             )
             if best is None or rank < best[0]:

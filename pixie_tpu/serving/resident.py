@@ -272,11 +272,7 @@ class ResidentRing:
         total = self.d * self.nblk * self.b
         W = self.window_rows
         use_codec = flags.staging_codec
-        # r22: the ring's encode bar rides the same learned codec-vs-raw
-        # rate the cold staging path uses (flag exactly when cold/off).
-        from pixie_tpu.parallel.staging import codec_min_ratio
-
-        min_ratio = codec_min_ratio()
+        min_ratio = flags.staging_codec_min_ratio
         blocks = {}
         nbytes = 0
         wire = 0

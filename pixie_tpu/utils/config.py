@@ -282,15 +282,17 @@ define_flag(
     "mesh fold dispatch: a dispatch that blocks past the deadline is "
     "treated as a hung collective and re-planned on the next "
     "degradation rung (pixie_tpu/distributed/mesh.py ladder). 0 = "
-    "derive the deadline from the r22 CostModel prediction x "
-    "mesh_watchdog_rail_factor when the model has an opinion (no "
-    "opinion = no watchdog). Negative disables the watchdog outright.",
+    "derive the deadline from the executor's own completed walls: "
+    "max(0.25, mesh_watchdog_rail_factor x the fold signature's slowest "
+    "wall, 4 x the slowest wall of any signature), and no watchdog for "
+    "a signature that has not completed a dispatch yet. Negative "
+    "disables the watchdog outright.",
 )
 define_flag(
     "mesh_watchdog_rail_factor",
     32.0,
-    help_="Multiplier on the r22 CostModel's predicted fold-dispatch "
-    "seconds when deriving the collective-watchdog deadline (only when "
+    help_="Multiplier on a fold signature's slowest completed dispatch "
+    "wall when deriving the collective-watchdog deadline (only when "
     "mesh_dispatch_timeout_s is 0). Generous by design: the watchdog "
     "exists to catch HUNG collectives, not slow ones — a false trip "
     "costs a full re-plan on the degraded rung.",

@@ -39,19 +39,6 @@ AGENT_EXPIRY_S = flags.agent_expiry_s
 
 _log = logging.getLogger("pixie_tpu.broker")
 
-# r22 learned cost model, resolved lazily (serving's package init
-# transitively imports this module through controller -> vizier.slo).
-_COST_MODEL = None
-
-
-def _cost_model():
-    global _COST_MODEL
-    if _COST_MODEL is None:
-        from pixie_tpu.serving import cost_model
-
-        _COST_MODEL = cost_model
-    return _COST_MODEL
-
 # Broker-side query counters on the shared registry so /metrics reflects
 # them (r11 satellite — ad-hoc totals were invisible to the endpoint).
 _M = metrics_registry()
@@ -775,19 +762,7 @@ class QueryBroker:
                 v = st.get(q)
                 if v:
                     vals.append(float(v))
-        raw = max(vals) / 1e3 if vals else None
-        # r22: the cost model ingests the instantaneous per-program-key
-        # quantiles into decayed reservoirs and answers with a smoothed
-        # estimate, clamped to [raw/rail, raw*rail] — one spiky
-        # heartbeat no longer whipsaws the hedge timer. Cold, shadow,
-        # or disabled: ``raw`` unchanged (the exact r17 value); no data
-        # at all still means no hedge.
-        cm = _cost_model()
-        if cm.ACTIVE:
-            pred = cm.hedge_delay_s(keys, view, q, raw)
-            if pred is not None:
-                return pred
-        return raw
+        return max(vals) / 1e3 if vals else None
 
     def _plan_with_replica_fallback(self, planner, logical, state):
         """Distributed planning, with a failover-mode fallback: when NO
@@ -871,22 +846,6 @@ class QueryBroker:
                 pass  # advisory: estimation must never fail a query
         return total
 
-    def _estimate_seconds(self, est_bytes: int) -> float:
-        """r22 advisory next to the bytes estimate: predicted staging
-        seconds for the estimated footprint plus the median whole-offload
-        fold — the cost model's answer to "how long will this admission
-        hold its slot". 0 cold/shadow/off (the signal vanishes; nothing
-        downstream rejects on it)."""
-        cm = _cost_model()
-        if not cm.ACTIVE or cm.SHADOW:
-            return 0.0
-        try:
-            total = cm.estimate_seconds_for_bytes(int(est_bytes)) or 0.0
-            total += cm.fold_seconds_p50() or 0.0
-            return float(total)
-        except Exception:
-            return 0.0
-
     def execute_script(
         self,
         query: str,
@@ -934,11 +893,7 @@ class QueryBroker:
             )
         # may raise AdmissionRejected
         est_bytes = self._estimate_staging(query)
-        ticket = self.admission.acquire(
-            tenant,
-            estimated_bytes=est_bytes,
-            estimated_seconds=self._estimate_seconds(est_bytes),
-        )
+        ticket = self.admission.acquire(tenant, estimated_bytes=est_bytes)
         try:
             return self._execute_script_inner(
                 query, timeout_s, now_ns, script_args, analyze,

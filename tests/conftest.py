@@ -35,15 +35,3 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
-
-
-@pytest.fixture(autouse=True)
-def _fresh_cost_model():
-    """A cold r22 cost model for every test: learned timings from one
-    test must never flip a lane gate another test asserts on (a cold
-    model has no opinion, so every decision is the hand-tuned default).
-    Tests of the model itself warm it explicitly."""
-    from pixie_tpu.serving import cost_model
-
-    cost_model.reset()
-    yield

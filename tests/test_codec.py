@@ -831,3 +831,68 @@ def test_query_with_sorted_keys_gid_codec_bit_identical(mesh):
     for col in on:
         a, b = np.asarray(on[col]), np.asarray(off[col])
         assert a.dtype == b.dtype and np.array_equal(a, b), col
+
+
+_RESTAGE_PXL = (
+    "df = px.DataFrame(table='restaged')\n"
+    "s = df.groupby(['service']).agg(\n"
+    "    hi=('ts', px.max),\n"
+    "    lat=('latency', px.mean),\n"
+    ")\n"
+    "px.display(s, 'out')\n"
+)
+
+
+@pytest.mark.parametrize(
+    "column, kind", [("ts", "delta"), ("latency", None)],
+    ids=["compressible", "incompressible"],
+)
+def test_codec_plan_same_on_first_and_twentieth_restaging(
+    mesh, monkeypatch, column, kind
+):
+    """The codec bar is ``staging_codec_min_ratio``: a table restaged
+    twenty times (a new version each time) plans each column's encoder
+    the same way the twentieth time as the first."""
+    from pixie_tpu.parallel import staging
+
+    plans = []
+    plan_stream = staging.plan_stream
+
+    def spy(*a, **k):
+        plan = plan_stream(*a, **k)
+        plans.append({n: cp.kind for n, cp in plan.codecs.items()})
+        return plan
+
+    monkeypatch.setattr(staging, "plan_stream", spy)
+    flags.set("staging_codec", True)
+    flags.set("streaming_window_rows", 1024)
+    try:
+        c = Carnot(device_executor=MeshExecutor(mesh=mesh, block_rows=256))
+        rel = Relation.of(
+            ("time_", T, SemanticType.ST_TIME_NS),
+            ("service", S),
+            ("ts", I),
+            ("latency", F),
+        )
+        t = c.table_store.create_table("restaged", rel)
+        rng = np.random.default_rng(29)
+        n = 0
+        for _ in range(20):
+            m = 512
+            t.write_pydict(
+                {
+                    "time_": np.arange(n, n + m) * 10**6,
+                    "service": rng.choice(["a", "b", "c"], m).astype(object),
+                    "ts": 10**15 + np.arange(n, n + m) * 1000,
+                    "latency": rng.exponential(30.0, m),
+                }
+            )
+            n += m
+            c.execute_query(_RESTAGE_PXL)
+            assert not c.device_executor.fallback_errors
+    finally:
+        flags.reset("staging_codec")
+        flags.reset("streaming_window_rows")
+    assert len(plans) >= 20
+    assert plans[0].get(column) == kind
+    assert plans[-1].get(column) == plans[0].get(column)

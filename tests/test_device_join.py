@@ -337,3 +337,49 @@ def test_device_join_f64_payload_bit_exact(mesh, flagset):
     bits = lambda rows: np.asarray(rows["lat"], np.float64).view(np.int64)
     np.testing.assert_array_equal(bits(rows_d), bits(rows_h))
     assert set(special.view(np.int64)) <= set(bits(rows_d))
+
+
+def _has_join_program(cd) -> bool:
+    return any(
+        s.startswith("join|") for s in cd.device_executor._program_cache
+    )
+
+
+@pytest.mark.parametrize(
+    "offset, device", [(1, False), (0, True), (-1, True)],
+    ids=["below", "at", "above"],
+)
+def test_device_join_gate_at_min_rows(mesh, flagset, offset, device):
+    """The gate is the flag comparison: the device lane runs once the
+    two sides' rows reach ``device_join_min_rows``, and the result is
+    the host engine's either way."""
+    nl, nr = 300, 200
+    flagset("device_join_min_rows", nl + nr + offset)
+    cd, rows_d, rows_h = run_both(mesh, _join_query("inner"), nl, nr)
+    assert _has_join_program(cd) is device
+    assert _canon(rows_d)[0] == _canon(rows_h)[0]
+
+
+def test_device_join_flag_zero_forces_device_lane(mesh, flagset):
+    """device_join_min_rows=0 means the device lane always, down to a
+    70-row join."""
+    flagset("device_join_min_rows", 0)
+    cd, rows_d, rows_h = run_both(mesh, _join_query("inner"), 40, 30)
+    assert _has_join_program(cd)
+    assert _canon(rows_d)[0] == _canon(rows_h)[0]
+
+
+def test_cost_routed_join_bit_identical_whichever_lane(mesh, flagset):
+    """Each lane forced through ``device_join_min_rows``: the host lane
+    just above the two sides' rows, the device lane at them; both
+    return rows bit-identical to the host engine."""
+    nl, nr = 900, 600
+    q = _join_query("inner")
+    want = _canon(build_carnot(None, nl, nr).execute_query(q).table("out"))
+    for min_rows, device in ((nl + nr + 1, False), (nl + nr, True)):
+        flagset("device_join_min_rows", min_rows)
+        cd = build_carnot(MeshExecutor(mesh=mesh, block_rows=512), nl, nr)
+        got = _canon(cd.execute_query(q).table("out"))
+        assert _has_join_program(cd) is device
+        assert not cd.device_executor.fallback_errors
+        assert got == want

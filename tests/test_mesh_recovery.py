@@ -29,7 +29,6 @@ import pytest
 from pixie_tpu.distributed.mesh import MeshConfig, MeshGeometryError
 from pixie_tpu.engine import Carnot
 from pixie_tpu.parallel import MeshExecutor
-from pixie_tpu.serving import cost_model
 from pixie_tpu.types import DataType, Relation
 from pixie_tpu.utils import faults, flags, metrics_registry
 
@@ -340,29 +339,51 @@ def test_watchdog_disabled_paths(flagset):
     )
 
 
-def test_watchdog_deadline_derives_from_cost_model(flagset):
-    """Flag 0 (the default): the deadline is CostModel prediction x the
-    rail factor, floored at 0.25s — no opinion means no watchdog."""
-    flagset("mesh_dispatch_timeout_s", 0.0)
+SIG = "fold|mesh:hosts:2,d:4|x"
+OTHER = "bfold|mesh:hosts:2,d:4|y"
+
+
+@pytest.mark.parametrize(
+    "flag, walls, want",
+    [
+        (0.0, [], None),
+        (0.0, [(OTHER, 0.05)], None),
+        (0.0, [(SIG, 0.05), (SIG, 0.02)], 0.05 * 32.0),
+        (0.0, [(SIG, 1e-4)], 0.25),
+        (0.0, [(SIG, 0.01), (OTHER, 0.5)], 0.5 * 4.0),
+        (2.5, [], 2.5),
+        (-1.0, [(SIG, 0.05)], None),
+    ],
+    ids=[
+        "cold", "cold-other-warm", "signature-slowest-wall", "floor",
+        "overall-slowest-wall", "positive-flag-wins", "negative-flag-off",
+    ],
+)
+def test_watchdog_deadline_from_completed_walls(flagset, flag, walls, want):
+    """Flag 0 (the default): a signature that has completed a dispatch
+    gets max(0.25 s, rail x its slowest wall, 4 x the slowest wall of
+    any signature); a cold one gets no watchdog. A positive flag wins,
+    a negative one turns the watchdog off."""
+    flagset("mesh_dispatch_timeout_s", flag)
     flagset("mesh_watchdog_rail_factor", 32.0)
     ex = MeshExecutor(
         block_rows=256, mesh_config=MeshConfig.parse("hosts:2,d:4", 8)
     )
-    assert ex._watchdog_deadline("fold|mesh:hosts:2,d:4|x") is None
-    cost_model.set_enabled(True)
-    sig = "fold|mesh:hosts:2,d:4|x"
-    for _ in range(3):  # cost_model_min_samples
-        cost_model.observe(sig, 0, 0.05)
-    d = ex._watchdog_deadline(sig)
-    assert d is not None and abs(d - 0.05 * 32.0) < 1e-6
-    # Microsecond-scale predictions ride the 0.25s jitter floor.
-    sig2 = "bfold|mesh:hosts:2,d:4|y"
-    for _ in range(3):
-        cost_model.observe(sig2, 0, 1e-4)
-    assert ex._watchdog_deadline(sig2) == 0.25
-    # An explicit positive flag wins over the model.
-    flagset("mesh_dispatch_timeout_s", 2.5)
-    assert ex._watchdog_deadline(sig) == 2.5
+    for sig, wall in walls:
+        ex._note_dispatch_wall(wall, sig)
+    got = ex._watchdog_deadline(SIG)
+    assert got == (None if want is None else pytest.approx(want))
+
+
+def test_watchdog_deadline_arms_after_a_completed_dispatch(flagset):
+    flagset("mesh_dispatch_timeout_s", 0.0)
+    ex = MeshExecutor(
+        block_rows=256, mesh_config=MeshConfig.parse("hosts:2,d:4", 8)
+    )
+    assert ex._watchdog_deadline(SIG) is None
+    assert ex._mesh_dispatch(lambda: 7, what="test", fold_sig=SIG) == 7
+    assert ex._watchdog_deadline(SIG) >= 0.25
+    assert ex._watchdog_deadline(OTHER) is None
 
 
 def test_watchdog_timeout_recovers_through_the_ladder(flagset, monkeypatch):

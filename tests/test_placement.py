@@ -785,3 +785,59 @@ def test_host_func_lut_predicate_batch_bit_identical(mesh):
         flags.reset("shared_scan_window_ms")
         flags.reset("shared_scan_predicate_batching")
         flags.reset("shared_scans")
+
+
+def test_hedge_placement_and_admission_read_their_rules(monkeypatch):
+    """The hedge delay is the ``hedge_quantile`` of the heartbeats'
+    fold-latency view, however often it was read before; an agent with
+    no latency history ranks ``cold``; admission carries no predicted
+    seconds, only the staging-bytes estimate."""
+    import types
+
+    from pixie_tpu.serving.admission import AdmissionController
+
+    monkeypatch.setattr(broker_mod, "fragment_program_key", lambda f: f)
+    saved = flags.get("hedge_delay_ms"), flags.get("hedge_quantile")
+    flags.set("hedge_delay_ms", 0.0)
+    flags.set("hedge_quantile", 0.5)
+    try:
+        views = [
+            {"pk1": {"a0": {"p50_ms": 100.0}, "a1": {"p50_ms": 40.0}}},
+            {"pk1": {"a0": {"p50_ms": 4.0}}},
+        ]
+        for view in views:
+            fake = types.SimpleNamespace(
+                tracker=types.SimpleNamespace(fold_latency_view=lambda v=view: v)
+            )
+            plan = types.SimpleNamespace(fragments=["pk1"])
+            want = max(st["p50_ms"] for st in view["pk1"].values()) / 1e3
+            for _ in range(5):
+                got = broker_mod.QueryBroker._hedge_delay_s(fake, plan)
+                assert got == pytest.approx(want)
+        empty = types.SimpleNamespace(
+            tracker=types.SimpleNamespace(fold_latency_view=lambda: {})
+        )
+        assert broker_mod.QueryBroker._hedge_delay_s(empty, plan) is None
+    finally:
+        flags.set("hedge_delay_ms", saved[0])
+        flags.set("hedge_quantile", saved[1])
+
+    plane = PlacementPlane()
+    view = [_agent("pem1", tables=NEEDED), _agent("pem2", tables=NEEDED)]
+    lat = {"progA": {"pem2": {"p50_ms": 5.0, "p99_ms": 9.0, "n": 9}}}
+    assert plane.decide(view[1:], NEEDED, fold_latency=lat) == (
+        "pem2", "latency_fallback",
+    )
+    assert plane.decide(view[:1], NEEDED, fold_latency=lat) == ("pem1", "cold")
+    # cold and latency_fallback share a rung: unmeasured pem1 ranks at
+    # latency 0, not at a predicted one.
+    assert plane.decide(view, NEEDED, fold_latency=lat) == ("pem1", "cold")
+
+    ctl = AdmissionController(max_concurrent=2, max_queue=2, timeout_s=1.0)
+    with pytest.raises(TypeError):
+        ctl.acquire("t", estimated_bytes=0, estimated_seconds=1.0)
+    ticket = ctl.acquire("t", estimated_bytes=1 << 20)
+    assert not hasattr(ticket, "estimated_seconds")
+    assert not any("predicted" in k for k in ctl.snapshot())
+    ticket.release()
+    assert ctl.snapshot()["active"] == 0

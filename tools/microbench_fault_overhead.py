@@ -36,11 +36,6 @@ broker query for a script NO view serves — with a live registry and a
 registered decoy view, the non-view path pays one flag check plus a
 probe-cache lookup resolving to a cached miss entry.
 
-Also gates (r22) the cost-model hooks: <1% modeled on the warm fold
-with the model DISABLED (the ``cm = _cost_model(); if cm.ACTIVE:``
-idiom at every observation recorder and lane gate), censused from the
-observations an enabled run ingests.
-
 Also gates (r23) the mesh recovery plane: <1% modeled on the warm fold
 at the default single-axis geometry, where every sharded dispatch pays
 exactly one axis-count branch in _mesh_dispatch (no fault-site probes,
@@ -422,63 +417,6 @@ def main() -> None:
         f"attribution: {warm_attr_census} hooks/warm-query, disabled "
         f"modeled {warm_attr_pct:.4f}% warm / 0% rtt; enabled A/B "
         f"{profiler_overhead['warm_enabled_delta_pct']:+.2f}% warm"
-    )
-
-    # -- cost-model overhead (r22) -------------------------------------------
-    # Same method: (a) per-check cost of the disabled call-site idiom
-    # (``cm = _cost_model(); if cm.ACTIVE:`` — a cached-module global
-    # load + attribute load + branch; the lazy resolver is measured,
-    # not guessed); (b) census of model hooks per warm query, measured
-    # as the observations an ENABLED run ingests (each = one gated
-    # check that passed) plus the constant decision-gate checks the
-    # warm fold path crosses (the sorted-lane gate, the fold-dispatch
-    # recorder, the codec/join gates the plan touches); (c) modeled
-    # disabled overhead = census * per_check_ns / op_ns, gated <1%,
-    # plus a direct enabled-vs-disabled A/B. The transport RTT has
-    # ZERO cost-model hooks.
-    from pixie_tpu.parallel import pipeline as _pl
-    from pixie_tpu.serving import cost_model
-
-    def _cm_check_ns(iters: int = 1_000_000) -> float:
-        cost_model.set_enabled(False)
-        t0 = time.perf_counter_ns()
-        for _ in range(iters):
-            cm = _pl._cost_model()
-            if cm.ACTIVE:
-                raise AssertionError
-        return (time.perf_counter_ns() - t0) / iters
-
-    cm_check_ns = _cm_check_ns()
-    cost_model.reset()  # cold + gates resynced from flags (default on)
-    c.execute_query(query)
-    # Observations ingested + the warm path's constant gate checks (the
-    # r8 sorted-lane decision and the whole-offload fold recorder).
-    warm_cm_census = (
-        sum(cost_model.model().sample_counts().values()) + 2
-    )
-    cost_model.reset()
-    warm_cm_on_ns = run_warm(warm_runs)
-    cost_model.set_enabled(False)
-    warm_cm_off_ns = run_warm(warm_runs)
-    cost_model.reset()  # default posture, no learned bench state
-    warm_cm_pct = 100.0 * warm_cm_census * cm_check_ns / warm_cm_off_ns
-    cost_model_overhead = {
-        "cost_model_check_disabled_ns": round(cm_check_ns, 2),
-        "warm_hooks_per_query": int(warm_cm_census),
-        "warm_disabled_modeled_pct": round(warm_cm_pct, 5),
-        "warm_enabled_delta_pct": round(
-            100.0 * (warm_cm_on_ns - warm_cm_off_ns)
-            / warm_cm_off_ns, 3
-        ),
-        "rtt_hooks_per_rtt": 0,  # no cost-model hooks on the transport
-        "rtt_disabled_modeled_pct": 0.0,
-        "pass_under_1pct": bool(warm_cm_pct < 1.0),
-    }
-    log(
-        f"cost model: {warm_cm_census} hooks/warm-query at "
-        f"{cm_check_ns:.1f}ns -> {warm_cm_pct:.4f}% disabled modeled; "
-        f"enabled A/B "
-        f"{cost_model_overhead['warm_enabled_delta_pct']:+.2f}% warm"
     )
 
     # -- mesh recovery overhead (r23) ----------------------------------------
@@ -872,7 +810,6 @@ def main() -> None:
             and profiler_overhead["pass_under_1pct"]
             and failover_overhead["pass_under_1pct"]
             and views_overhead["pass_under_1pct"]
-            and cost_model_overhead["pass_under_1pct"]
             and mesh_recovery_overhead["pass_under_1pct"]
             and ingest_overhead["pass_under_1pct"]
         ),
@@ -884,7 +821,6 @@ def main() -> None:
     out["profiler_overhead"] = profiler_overhead
     out["failover_overhead"] = failover_overhead
     out["views_overhead"] = views_overhead
-    out["cost_model_overhead"] = cost_model_overhead
     out["mesh_recovery_overhead"] = mesh_recovery_overhead
     out["ingest_overhead"] = ingest_overhead
     print(json.dumps(out))
@@ -900,8 +836,7 @@ def main() -> None:
                 "ack_overhead", "trace_overhead",
                 "durability_overhead", "profiler_overhead",
                 "failover_overhead", "views_overhead",
-                "cost_model_overhead", "mesh_recovery_overhead",
-                "ingest_overhead",
+                "mesh_recovery_overhead", "ingest_overhead",
             )
         }
         detail["ack_overhead"] = ack_overhead
@@ -910,7 +845,6 @@ def main() -> None:
         detail["profiler_overhead"] = profiler_overhead
         detail["failover_overhead"] = failover_overhead
         detail["views_overhead"] = views_overhead
-        detail["cost_model_overhead"] = cost_model_overhead
         detail["mesh_recovery_overhead"] = mesh_recovery_overhead
         detail["ingest_overhead"] = ingest_overhead
         with open(path, "w") as f:
@@ -919,8 +853,8 @@ def main() -> None:
         log(
             "BENCH_DETAIL.json updated (fault_overhead, ack_overhead, "
             "trace_overhead, durability_overhead, profiler_overhead, "
-            "failover_overhead, views_overhead, cost_model_overhead, "
-            "mesh_recovery_overhead, ingest_overhead)"
+            "failover_overhead, views_overhead, mesh_recovery_overhead, "
+            "ingest_overhead)"
         )
 
     if not out["pass_under_1pct"]:

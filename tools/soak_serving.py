@@ -295,7 +295,6 @@ def run_soak(
     agents: int = 1,
     fleet_tables: int = 0,
     views: bool = False,
-    cost_model: bool = False,
 ) -> dict:
     """Build the cluster, run the soak (serving flags pinned for the
     run, restored after), return the report dict. ``chaos`` arms
@@ -312,13 +311,7 @@ def run_soak(
     workload: the ``views_queries`` panel is registered as materialized
     views after the serial baselines, and the concurrent phase measures
     view hit rate + fold-dispatch reduction vs the views-off cost of
-    one full fold per request; the report gains a ``views`` block.
-    ``cost_model`` (r22) runs the soak against a COLD learned cost
-    model (reset before the run, flag pinned on): the report gains a
-    ``cost_model`` block — per-family predicted-vs-actual fold cost
-    (``error_snapshot``), the observation census, and (with
-    ``controller``) the predictive-vs-reactive split of the actuation
-    trail, the delta against the pure-MIMD r16 baseline."""
+    one full fold per request; the report gains a ``views`` block."""
     from pixie_tpu.utils import flags
 
     soak_flags = {
@@ -389,51 +382,14 @@ def run_soak(
                 "shared_scan_predicate_batching": False,
             }
         )
-    if cost_model:
-        # The import defines the r22 flags; reset AFTER pinning them so
-        # the gates resync — cold start: convergence during THIS soak
-        # is what's measured.
-        from pixie_tpu.serving import cost_model as _cm
-
-        soak_flags["cost_model"] = True
     for name, value in soak_flags.items():
         flags.set(name, value)
-    if cost_model:
-        _cm.reset()
     try:
-        report = _run_soak_inner(
+        return _run_soak_inner(
             clients, requests_per_client, qps_per_client, rows,
             hbm_budget_mb, window_ms, seed, chaos, profile,
             agents, fleet_tables, views,
         )
-        if cost_model:
-            from pixie_tpu.serving import cost_model as _cm
-
-            trail = (report.get("controller") or {}).get(
-                "actuations", []
-            )
-            report["cost_model"] = {
-                # Relative |predicted - measured| / measured per family,
-                # predict-before-ingest (honest: the sample had not yet
-                # influenced the model when the prediction was made).
-                "error_snapshot": _cm.error_snapshot(),
-                "sample_counts": _cm.model().sample_counts(),
-                # r22 controller upgrade: raises fired by the predicted
-                # backlog wait vs the reactive windowed quantile. The
-                # pure-MIMD r16 baseline has zero predictive entries.
-                "predictive_actuations": sum(
-                    1
-                    for a in trail
-                    if a.get("reason") == "predicted_wait_over_target"
-                ),
-                "reactive_actuations": sum(
-                    1
-                    for a in trail
-                    if a.get("reason") == "wait_p50_over_target"
-                ),
-            }
-            _cm.reset()  # leave no learned soak state behind
-        return report
     finally:
         # Restore env/default flag values so an embedding caller
         # (bench.py's concurrency config) is not left in serving mode.
@@ -1372,17 +1328,6 @@ def main() -> int:
         "'controller' block carries the actuation trail — which knobs "
         "moved, from what, why, on which window signals.",
     )
-    ap.add_argument(
-        "--cost-model", action="store_true",
-        default=bool(int(os.environ.get("SOAK_COST_MODEL", "0"))),
-        help="r22: run against a COLD learned cost model (reset at "
-        "start, flag cost_model pinned on). The report's 'cost_model' "
-        "block carries per-family predicted-vs-actual fold cost "
-        "(relative error quantiles), the observation census, and — "
-        "with --controller — how many concurrency raises came from "
-        "the predicted backlog wait vs the reactive quantile (the "
-        "delta against the pure-MIMD r16 baseline).",
-    )
     args = ap.parse_args()
     report = run_soak(
         clients=args.clients,
@@ -1398,7 +1343,6 @@ def main() -> int:
         agents=args.agents,
         fleet_tables=args.fleet_tables,
         views=args.views,
-        cost_model=args.cost_model,
     )
     print(json.dumps(report, indent=1))
     path = os.environ.get("SOAK_JSON")
