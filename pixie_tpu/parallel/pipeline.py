@@ -154,6 +154,7 @@ from pixie_tpu.parallel.staging import (  # noqa: E402
     count_device_aggs,
     count_key_evals,
     count_read_batches,
+    count_read_visits,
     reset_cold_profile,
     timed as _timed,
 )
@@ -4768,7 +4769,7 @@ class MeshExecutor:
         if cached is not None:
             self._keyplan_cache.move_to_end(kp_key)
             if sp is not None:
-                sp.set(batches=0, evals=0, cached=True)
+                sp.set(batches=0, visits=0, evals=0, cached=True)
             return cached
         key_refs = set()
         for g in groups:
@@ -4848,9 +4849,13 @@ class MeshExecutor:
             pad = np.minimum(np.arange(chunk_rows), pending_rows - 1)
             densify(RowBatch.concat(pending).take(pad), pending_rows)
         count_read_batches(batches)
+        count_read_visits(cur.segments_visited)
         count_key_evals(len(gid_parts))
         if sp is not None:
-            sp.set(batches=batches, evals=len(gid_parts), cached=False)
+            sp.set(
+                batches=batches, visits=cur.segments_visited,
+                evals=len(gid_parts), cached=False,
+            )
         gids = (
             np.concatenate(gid_parts) if gid_parts else np.empty(0, np.int32)
         )

@@ -50,6 +50,13 @@ def count_read_batches(n: int) -> None:
     COLD_PROFILE["read_batches"] = COLD_PROFILE.get("read_batches", 0.0) + n
 
 
+def count_read_visits(n: int) -> None:
+    """COLD_PROFILE["read_visits"]: table segments that one pass's
+    cursor examined (``Cursor.segments_visited``), counted beside
+    count_read_batches by the same helpers."""
+    COLD_PROFILE["read_visits"] = COLD_PROFILE.get("read_visits", 0.0) + n
+
+
 def count_key_evals(n: int) -> None:
     """COLD_PROFILE["key_evals"]: group-key evaluations that one key
     plan ran (one per fixed-size chunk of rows, MeshExecutor._plan_keys)."""
@@ -214,6 +221,7 @@ def read_columns_windowed(
         if b.num_rows or b.eow:
             batches.append(b)
     count_read_batches(sum(1 for b in batches if b.num_rows))
+    count_read_visits(cur.segments_visited)
     cols: dict[str, np.ndarray] = {}
     n = sum(b.num_rows for b in batches)
     for name in columns:

@@ -327,7 +327,7 @@ class MaterializedView:
         row = from_row
         rows = 0
         while row < to_row:
-            batch, nxt = table._read_from(row, _CHUNK_ROWS, None, None)
+            batch, nxt, _ = table._read_from(row, _CHUNK_ROWS, None, None)
             if batch is None or nxt <= row:
                 break
             start_id = nxt - batch.num_rows

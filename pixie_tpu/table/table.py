@@ -13,7 +13,9 @@ so cold reads stage to HBM with zero re-chunking.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
+import operator
 import threading
 from typing import Optional
 
@@ -58,6 +60,9 @@ class _Segment:
     @property
     def end_row_id(self) -> int:
         return self.first_row_id + self.num_rows
+
+
+_FIRST_ROW_ID = operator.attrgetter("first_row_id")
 
 
 class Table:
@@ -295,10 +300,22 @@ class Table:
 
     def _read_from(
         self, row_id: int, max_rows: int, start_time, stop_time
-    ) -> tuple[Optional[RowBatch], int]:
-        """Return (batch, next_row_id). Batch is None if nothing available yet."""
+    ) -> tuple[Optional[RowBatch], int, int]:
+        """Return (batch, next_row_id, segments_visited). Batch is None if
+        nothing is available yet.
+
+        The loop starts at the segment holding ``row_id``, found by
+        bisection on ``first_row_id``: segments are in row-id order and
+        row ids are global and monotonic, so every segment before it ends
+        at or before ``row_id`` whatever compaction or expiry did since
+        the last call. ``segments_visited`` counts the loop's segments."""
         with self._lock:
-            for seg in self._segments:
+            segs = self._segments
+            i = bisect.bisect_right(segs, row_id, key=_FIRST_ROW_ID) - 1
+            visited = 0
+            for j in range(max(i, 0), len(segs)):
+                seg = segs[j]
+                visited += 1
                 if seg.end_row_id <= row_id:
                     continue
                 # Time-slice pruning on segment [min,max] bounds.
@@ -306,7 +323,7 @@ class Table:
                     row_id = seg.end_row_id
                     continue
                 if stop_time is not None and seg.min_time > stop_time:
-                    return None, row_id  # telemetry is time-ordered; done
+                    return None, row_id, visited  # telemetry is time-ordered; done
                 lo = max(0, row_id - seg.first_row_id)
                 hi = min(seg.num_rows, lo + max_rows)
                 chunk = seg.batch.slice(lo, hi)
@@ -322,8 +339,8 @@ class Table:
                         mask &= t <= stop_time
                     if not mask.all():
                         chunk = chunk.take(np.nonzero(mask)[0])
-                return chunk, next_id
-            return None, max(row_id, self._next_row_id)
+                return chunk, next_id, visited
+            return None, max(row_id, self._next_row_id), visited
 
 
 class Cursor:
@@ -341,6 +358,7 @@ class Cursor:
         self.streaming = streaming
         self._row_id = table.min_row_id()
         self.rows_skipped = 0
+        self.segments_visited = 0  # summed over next_batch calls
         self._done = False
 
     def done(self) -> bool:
@@ -358,9 +376,10 @@ class Cursor:
         if self._row_id < min_id:
             self.rows_skipped += min_id - self._row_id
             self._row_id = min_id
-        batch, next_id = self.table._read_from(
+        batch, next_id, visited = self.table._read_from(
             self._row_id, max_rows, self.start_time, self.stop_time
         )
+        self.segments_visited += visited
         advanced = next_id > self._row_id
         self._row_id = next_id
         if batch is None and not advanced:

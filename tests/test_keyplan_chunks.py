@@ -247,8 +247,12 @@ def test_read_batches_counts_cursor_batches():
     assert walked == 4 + PUSHES  # every cold batch and every push
     res = _run(c, _query(BIN_KEYS, first, last))
     prof = reset_cold_profile()
-    # Key planning and read_columns each walk the span once.
+    # Key planning and read_columns each walk the span once, and each
+    # batch's cursor call resumes at its own segment: one visit a batch.
     assert prof["read_batches"] == 2 * walked
+    assert prof["read_visits"] == 2 * walked
     assert prof["key_evals"] == 1
     (plan,) = [s for s in res.trace_spans if s["name"] == "device.plan_keys"]
-    assert plan["attrs"] == {"batches": walked, "evals": 1, "cached": False}
+    assert plan["attrs"] == {
+        "batches": walked, "visits": walked, "evals": 1, "cached": False
+    }

@@ -1,8 +1,12 @@
 """Table store tests (ref model: src/table_store/table/table_test.cc)."""
 
+import functools
+
 import numpy as np
+import pytest
 
 from pixie_tpu.table import DictColumn, RowBatch, StringDictionary, Table, TableStore
+from pixie_tpu.table.table import DEFAULT_COMPACTED_ROWS
 from pixie_tpu.types import DataType, Relation
 
 REL = Relation.of(
@@ -138,3 +142,175 @@ def test_streaming_cursor():
     t.stop()
     assert cur.next_batch() is None
     assert cur.done()
+
+
+# -- cursor resumes: the bisected read against a linear-scan reference ----
+
+
+def _linear_read_from(table, row_id, max_rows, start_time, stop_time):
+    """Table._read_from as a scan from the first segment on every call:
+    the reference a bisected start must answer exactly as."""
+    with table._lock:
+        visited = 0
+        for seg in table._segments:
+            visited += 1
+            if seg.end_row_id <= row_id:
+                continue
+            if start_time is not None and seg.max_time < start_time:
+                row_id = seg.end_row_id
+                continue
+            if stop_time is not None and seg.min_time > stop_time:
+                return None, row_id, visited
+            lo = max(0, row_id - seg.first_row_id)
+            hi = min(seg.num_rows, lo + max_rows)
+            chunk = seg.batch.slice(lo, hi)
+            next_id = seg.first_row_id + hi
+            if start_time is not None or stop_time is not None:
+                t = np.asarray(chunk.columns[table._time_idx])
+                mask = np.ones(len(t), dtype=bool)
+                if start_time is not None:
+                    mask &= t >= start_time
+                if stop_time is not None:
+                    mask &= t <= stop_time
+                if not mask.all():
+                    chunk = chunk.take(np.nonzero(mask)[0])
+            return chunk, next_id, visited
+        return None, max(row_id, table._next_row_id), visited
+
+
+PUSH = 5  # rows a push; row i has time_ 10 * i
+
+
+def _push(t, k, eow=False):
+    """Write push k: rows [k * PUSH, (k + 1) * PUSH)."""
+    ids = np.arange(k * PUSH, (k + 1) * PUSH)
+    t.write_pydict(
+        {
+            "time_": ids * 10,
+            "latency": ids.astype(np.float64),
+            "service": ["s%d" % (i % 3) for i in ids],
+        },
+        eow=eow,
+    )
+
+
+def _pushes(t, lo, hi, eow_every=0):
+    for k in range(lo, hi):
+        _push(t, k, eow=bool(eow_every) and k % eow_every == eow_every - 1)
+
+
+def _cold_prefix(t):
+    """40 pushes compacted into 8-row cold segments (25 of them), then 20
+    more pushes: the range [45, 270] rows starts in the cold prefix."""
+    _pushes(t, 0, 40)
+    t.compact()
+    _pushes(t, 40, 60)
+
+
+def _eow_markers(t):
+    """Pushes closing a window every third push, and zero-row eow pushes
+    between some of them."""
+    for k in range(30):
+        _push(t, k, eow=k % 3 == 2)
+        if k % 7 == 6:
+            t.write_pydict({"time_": [], "latency": [], "service": []}, eow=True)
+
+
+def _expire_leading(t, step):
+    if step in (3, 8):  # each drops the oldest pushes from the ring
+        _pushes(t, 30 + 10 * step, 40 + 10 * step)
+
+
+def _stream(t, step):
+    if step % 2 and step < 40:
+        _push(t, 100 + step, eow=step % 5 == 0)
+    if step == 45:
+        t.stop()
+
+
+WALKS = {
+    # name: (table kwargs, fill, cursor kwargs, max_rows, between calls)
+    "unbounded": ({}, lambda t: _pushes(t, 0, 40, 4), {}, 3, None),
+    "time_bounded": (
+        {}, lambda t: _pushes(t, 0, 40, 4),
+        {"start_time": 333, "stop_time": 1566}, 4, None,
+    ),
+    "cold_prefix_into_pushes": (
+        {"compacted_rows": 8}, _cold_prefix,
+        {"start_time": 450, "stop_time": 2700}, 3, None,
+    ),
+    "eow_markers": ({}, _eow_markers, {"start_time": 120}, 4, None),
+    "compact_between_calls": (
+        {"compacted_rows": 8}, lambda t: _pushes(t, 0, 30, 3),
+        {"start_time": 75, "stop_time": 1400}, 3,
+        lambda t, step: step == 4 and t.compact(),
+    ),
+    "expire_between_calls": (
+        {"size_limit": 20 * PUSH * 20}, lambda t: _pushes(t, 0, 20, 2),
+        {}, 4, _expire_leading,
+    ),
+    "streaming_appends": (
+        {}, lambda t: _pushes(t, 0, 6, 2), {"streaming": True}, 3, _stream,
+    ),
+}
+
+
+def _walk(t, cursor_kw, max_rows, between):
+    """Every next_batch answer (rows, eow, eos, or None), then the
+    cursor's skipped rows and done state."""
+    cur = t.cursor(**cursor_kw)
+    out = []
+    for step in range(200):
+        if between is not None:
+            between(t, step)
+        if cur.done():
+            break
+        b = cur.next_batch(max_rows)
+        out.append(None if b is None else (b.to_pydict(), b.eow, b.eos))
+    out.append((cur.rows_skipped, cur.done()))
+    return out, cur
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_cursor_walk_matches_linear_scan(case):
+    table_kw, fill, cursor_kw, max_rows, between = WALKS[case]
+    bisected, linear = Table(REL, **table_kw), Table(REL, **table_kw)
+    linear._read_from = functools.partial(_linear_read_from, linear)
+    for t in (bisected, linear):
+        fill(t)
+    got, cur = _walk(bisected, cursor_kw, max_rows, between)
+    want, ref = _walk(linear, cursor_kw, max_rows, between)
+    assert got == want
+    assert sum(b is not None and len(b[0]["time_"]) for b in got[:-1]) > 0
+    assert cur.segments_visited <= ref.segments_visited
+    if case == "expire_between_calls":
+        assert bisected.stats().batches_expired and cur.rows_skipped
+
+
+@pytest.mark.parametrize("max_rows", [DEFAULT_COMPACTED_ROWS, 2])
+def test_cursor_visits_grow_with_batches_not_segments(max_rows):
+    """2,000 pushes behind 500 cold segments: a time-bounded walk
+    visits the segments before its range once, then one a batch."""
+    cold, pushes, rows = 500, 2000, 3
+    t = Table(REL, compacted_rows=8)
+    ids = np.arange(cold * 8)
+    t.write_pydict(
+        {"time_": ids, "latency": ids * 1.0, "service": ["a"] * len(ids)}
+    )
+    assert t.compact() == cold
+    for k in range(pushes):
+        ids = cold * 8 + k * rows + np.arange(rows)
+        t.write_pydict(
+            {"time_": ids, "latency": ids * 1.0, "service": ["b"] * rows}
+        )
+    cur = t.cursor(start_time=cold * 8, stop_time=None)
+    batches = n = 0
+    while not cur.done():
+        b = cur.next_batch(max_rows)
+        if b is None:
+            break
+        batches += 1
+        n += b.num_rows
+    assert n == pushes * rows
+    assert batches == pushes * -(-rows // min(rows, max_rows))
+    assert cur.segments_visited <= cold + batches + 2

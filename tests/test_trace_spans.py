@@ -87,8 +87,11 @@ def test_span_count_does_not_grow_with_pushes_or_windows():
         assert reset_cold_profile()["read_batches"] >= 2 * pushes
         counts[pushes] = collections.Counter(s["name"] for s in res.trace_spans)
         plan = [s for s in res.trace_spans if s["name"] == "device.plan_keys"]
-        # Every push is walked; the few rows are evaluated in one chunk.
-        assert plan[0]["attrs"] == {"batches": pushes, "evals": 1, "cached": False}
+        # Every push is walked, each read resuming at its own segment;
+        # the few rows are evaluated in one chunk.
+        assert plan[0]["attrs"] == {
+            "batches": pushes, "visits": pushes, "evals": 1, "cached": False
+        }
     assert counts[30] == counts[300]
     for name in ("query", "compile", "fragment", "device.execute",
                  "device.plan_keys", "device.stage", "device.finalize", "exec"):

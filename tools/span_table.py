@@ -63,6 +63,7 @@ def measure(cell, seed, seconds, trace, devices, peaks, t_start) -> dict:
         "breakdown": out.result.get("breakdown"),
         "span_self_ms": spans.self_ms(summary),
         "read_batches": [r.profile.get("read_batches") for r in out.records],
+        "read_visits": [r.profile.get("read_visits") for r in out.records],
     }
 
 
